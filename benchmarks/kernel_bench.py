@@ -108,7 +108,7 @@ def _cells(fast: bool):
 
     # block sizes from the same analytic model dispatch uses for its plans
     bt = dispatch.block_t_ghost(T, d, p)
-    bte = dispatch.block_t_ghost(T, d, d)
+    bte = dispatch.block_t_ghost(T, d, d, lane=True)
     bd, bp = dispatch.block_dp(T, d, p)
     bv = dispatch.block_v(T, d, V)
     mbd, mbp = dispatch.block_dp(C, d, p)

@@ -84,12 +84,11 @@ def _shard_call(mesh, fn, args, in_specs, out_specs, psum_axes=None):
     batch slice; ``psum_axes`` reduces sum-typed outputs (weighted grads)
     once across the batch axes — the single cross-device reduction per clip
     unit the mesh-lowered step pays."""
-    from jax.experimental.shard_map import shard_map
     body = fn
     if psum_axes:
         body = lambda *a: jax.lax.psum(fn(*a), psum_axes)
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def _local(shape, bdim: int, n: int) -> tuple:
@@ -631,17 +630,16 @@ def bk_clipped_sum(apply_fn, params, batch, cfg, mesh=None, rng=None):
                 if shard:
                     # NOT _shard_call: only the grad psums across the batch
                     # axes — the per-sample sq norms stay batch-sharded
-                    from jax.experimental.shard_map import shard_map
                     bdim = act.ndim - 3
                     body = lambda a, d, v: (
                         (lambda g_s: (jax.lax.psum(g_s[0], ba), g_s[1]))
                         (fused(a, d, v)))
-                    G, sqk = shard_map(
+                    G, sqk = jax.shard_map(
                         body, mesh=mesh,
                         in_specs=(_bspec(act.ndim, bdim, ba),
                                   _bspec(ds.ndim, bdim, ba), P(ba)),
                         out_specs=(P(), P(ba)),
-                        check_rep=False)(act, ds, wv)
+                        check_vma=False)(act, ds, wv)
                 else:
                     G, sqk = fused(act, ds, wv)
                 flat_grads[wpath] = G.astype(w.dtype)

@@ -106,9 +106,9 @@ def counter_normal(rng, shape, dtype=jnp.float32, offsets=None,
     function of (key, linear index of x within ``full_shape``) — one
     threefry-2x32 block per element with the index as the counter (the raw
     block primitive: the high-level hashes pair positions across the array,
-    making values length-dependent), 24 mantissa bits to a (0,1) uniform,
-    then the inverse normal CDF. A device holding only the local block
-    passes its per-dim global ``offsets``; any partition of the same
+    making values length-dependent), then :func:`normal_from_bits`. A
+    device holding only the local block passes its per-dim global
+    ``offsets``; any partition of the same
     (key, full_shape) reproduces bitwise the same global tensor.
 
     Tensors past 2^32 elements split the counter across BOTH threefry
@@ -116,7 +116,6 @@ def counter_normal(rng, shape, dtype=jnp.float32, offsets=None,
     under 2^32 keep their exact pre-split draws), the leading-block index
     rides word 1."""
     from jax.extend.random import threefry2x32_p
-    from jax.scipy.special import ndtri
     full = tuple(full_shape) if full_shape is not None else tuple(shape)
     # split point: dims [k:] index counter word 0 exactly; dims [:k] word 1
     k, trail = len(full), 1
@@ -147,9 +146,19 @@ def counter_normal(rng, shape, dtype=jnp.float32, offsets=None,
     bits, _ = threefry2x32_p.bind(jnp.broadcast_to(key[0], lo.shape),
                                   jnp.broadcast_to(key[1], lo.shape),
                                   lo, hi)
-    bits = bits.reshape(shape)
+    return normal_from_bits(bits.reshape(shape), dtype)
+
+
+def normal_from_bits(bits, dtype=jnp.float32):
+    """uint32 random bits -> N(0,1): the top 24 bits to a (0, 1) uniform,
+    then the inverse normal CDF. In f32 the top cell's 1 - 2^-25 rounds to
+    1.0, where the inverse is +inf (once in 2^24 elements, so dozens of
+    times per step on a model of 10^9 params); it is held at the largest
+    f32 below 1 instead, and every other draw is unchanged."""
+    from jax.scipy.special import ndtri
     u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24) \
         + jnp.float32(2 ** -25)
+    u = jnp.minimum(u, jnp.float32(1 - 2 ** -24))
     return ndtri(u).astype(dtype)
 
 
@@ -194,9 +203,8 @@ def sharded_normal(rng, shape, dtype=jnp.float32, mesh=None, spec=None):
         return counter_normal(key, local_shape, dtype, offsets=offs,
                               full_shape=shape)
 
-    from jax.experimental.shard_map import shard_map
-    return shard_map(draw, mesh=mesh, in_specs=P(),
-                     out_specs=P(*tail), check_rep=False)(rng)
+    return jax.shard_map(draw, mesh=mesh, in_specs=P(),
+                         out_specs=P(*tail), check_vma=False)(rng)
 
 
 def _scale_for(sensitivity, path: str) -> float:

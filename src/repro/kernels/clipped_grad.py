@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import scalar_in_spec
+
 F32 = jnp.float32
 
 
@@ -29,7 +31,7 @@ def _kernel(a_ref, g_ref, c_ref, out_ref):
 
     a = a_ref[0, 0].astype(F32)               # (T, bd)
     g = g_ref[0, 0].astype(F32)               # (T, bp)
-    c = c_ref[0].astype(F32)                  # scalar clip factor
+    c = c_ref[b]                              # scalar clip factor (SMEM)
     tile = jax.lax.dot_general(a * c, g, (((0,), (0,)), ((), ())),
                                preferred_element_type=F32)
     out_ref[0] += tile
@@ -58,11 +60,11 @@ def clipped_grad(a, C, ds, block_d: int = 256, block_p: int = 256,
         in_specs=[
             pl.BlockSpec((1, 1, T, bd), lambda l, i, j, b: (l, b, 0, i)),
             pl.BlockSpec((1, 1, T, bp), lambda l, i, j, b: (l, b, 0, j)),
-            pl.BlockSpec((1,), lambda l, i, j, b: (b,)),
+            scalar_in_spec(),
         ],
         out_specs=pl.BlockSpec((1, bd, bp), lambda l, i, j, b: (l, i, j)),
         out_shape=jax.ShapeDtypeStruct((L, D, P), F32),
         interpret=interpret,
-    )(a, ds, C)
+    )(a, ds, C.astype(F32))
     out = out[:, :d, :p]
     return out[0] if squeeze else out

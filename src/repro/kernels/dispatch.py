@@ -11,7 +11,9 @@ choice — per tapped op:
               HBM, so records whose intermediate is tiny (fits in registers
               anyway, launch overhead dominates) stay on the jnp path;
   3. blocks   tile sizes chosen so one grid step's operands fit the VMEM
-              working-set budget, snapped to hardware-friendly multiples.
+              working-set budget, snapped to the multiples Mosaic accepts
+              (8 sublanes, 128 lanes, or the whole dim); a tap for which
+              no block fits runs on the jnp path.
 
 Plans are cached per (kind, method, shape, backend). ``autotune`` replaces
 the analytic block choice with measured timings on synthetic data (run it
@@ -41,7 +43,7 @@ VMEM_BUDGET = 6 * 2 ** 20
 KERNEL_MIN_INTERMEDIATE = 256
 
 _BT_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
-_BDP_CANDIDATES = (1024, 512, 256, 128, 64, 32, 16, 8)
+_BDP_CANDIDATES = (1024, 512, 256, 128)
 _BV_CANDIDATES = (4096, 2048, 1024, 512, 256, 128)
 
 _plan_cache: dict = {}
@@ -66,36 +68,49 @@ class Plan:
 
 
 # ------------------------------------------------------------- block model
-def block_t_ghost(T: int, d: int, p: int) -> int:
+def block_t_ghost(T: int, d: int, p: int, lane: bool = False) -> int:
     """Tile of the packed-triangular ghost-norm grid: 2bt(d+p) operands plus
-    3bt^2 live Gram registers per step."""
+    3bt^2 live Gram registers per step. ``lane``: the tile is also a lane
+    dim (the embedding kernel's row of ids), so below T it must be a
+    multiple of 128. 0 when no tile fits (route the tap to jnp)."""
     cap = _rup(min(T, _BT_CANDIDATES[0]), 8)
-    for bt in _BT_CANDIDATES:
-        if bt <= cap and 4 * (2 * bt * (d + p) + 3 * bt * bt) <= VMEM_BUDGET:
+    for bt in (cap,) + tuple(c for c in _BT_CANDIDATES if c < cap):
+        if lane and bt < T and bt % 128:
+            continue
+        if 4 * (2 * bt * (d + p) + 3 * bt * bt) <= VMEM_BUDGET:
             return bt
-    return 8
+    return 0
 
 
 def block_dp(T: int, d: int, p: int) -> tuple:
     """(bd, bp) for the instantiation-style grids: T(bd+bp) operands plus a
-    bd*bp tile per step."""
-    capd = _rup(min(d, _BDP_CANDIDATES[0]), 8)
-    capp = _rup(min(p, _BDP_CANDIDATES[0]), 8)
+    bd*bp tile per step. Both are lane dims: a multiple of 128, or the
+    whole dim when it is at most the largest tile. None when no pair fits
+    (route the tap to jnp)."""
     for b in _BDP_CANDIDATES:
-        bd, bp = min(b, capd), min(b, capp)
+        bd = d if d <= b else b
+        bp = p if p <= b else b
         if 4 * (T * (bd + bp) + bd * bp) <= VMEM_BUDGET:
             return bd, bp
-    return 8, 8
+    return None
 
 
 def block_v(T: int, d: int, vocab: int) -> int:
     """Vocab tile of the clipped-embedding-grad grid: T*bv one-hot + bv*d
-    output tile + T*d cotangents per step."""
+    output tile + T*d cotangents per step. 0 when no tile fits."""
     cap = _rup(min(vocab, _BV_CANDIDATES[0]), 128)
     for bv in _BV_CANDIDATES:
         if bv <= cap and 4 * (T * bv + bv * d + T * d) <= VMEM_BUDGET:
             return bv
-    return 128
+    return 0
+
+
+def _blocked(inter: int, method: str, names: tuple, values) -> Plan:
+    """Plan for a kernel that needs blocks: the jnp path when none fit."""
+    if not values:
+        return Plan("jnp", method, ())
+    values = values if isinstance(values, tuple) else (values,)
+    return Plan(_impl(inter), method, tuple(zip(names, values)))
 
 
 # -------------------------------------------------------------- impl model
@@ -144,19 +159,19 @@ def norm_plan(kind: str, act_shape, ds_shape, mode: str,
             from repro.core.ghost import prefer_ghost
             m = method or ("ghost" if mode == "bk" or prefer_ghost(T, d, p)
                            else "direct")
-            inter = L * B * (2 * T * T if m == "ghost" else d * p)
-            blocks = (("block_t", block_t_ghost(T, d, p)),) \
-                if m == "ghost" else \
-                tuple(zip(("block_d", "block_p"), block_dp(T, d, p)))
-            return Plan(_impl(inter), m, blocks)
+            if m == "ghost":
+                return _blocked(L * B * 2 * T * T, m, ("block_t",),
+                                block_t_ghost(T, d, p))
+            return _blocked(L * B * d * p, m, ("block_d", "block_p"),
+                            block_dp(T, d, p))
         if kind == "emb":
             ids = act_shape if len(act_shape) == 3 else (1,) + tuple(act_shape)
             L, B, T = ids
             d = ds_shape[-1]
             # ghost is the only sane norm for embeddings: direct would
             # instantiate (B, V, d); a 'direct' group override is ignored
-            return Plan(_impl(L * B * T * T), "ghost",
-                        (("block_t", block_t_ghost(T, d, d)),))
+            return _blocked(L * B * T * T, "ghost", ("block_t",),
+                            block_t_ghost(T, d, d, lane=True))
         if kind == "moe":
             a = act_shape if len(act_shape) == 5 else (1,) + tuple(act_shape)
             L, B, E, C, d = a
@@ -164,10 +179,10 @@ def norm_plan(kind: str, act_shape, ds_shape, mode: str,
             from repro.core.ghost import prefer_ghost
             m = method or ("ghost" if mode == "bk" or prefer_ghost(C, d, p)
                            else "direct")
-            inter = L * B * E * (2 * C * C if m == "ghost" else d * p)
-            blocks = () if m == "ghost" else \
-                tuple(zip(("block_d", "block_p"), block_dp(C, d, p)))
-            return Plan(_impl(inter), m, blocks)
+            if m == "ghost":
+                return Plan(_impl(L * B * E * 2 * C * C), m, ())
+            return _blocked(L * B * E * d * p, m, ("block_d", "block_p"),
+                            block_dp(C, d, p))
         raise ValueError(f"unknown tap kind {kind!r}")
 
     return _cached(key, mk)
@@ -224,20 +239,20 @@ def grad_plan(kind: str, act_shape, ds_shape, vocab: int = 0) -> Plan:
             p = ds_shape[-1]
             # the kernel fuses diag(C): the avoided HBM intermediate is the
             # (L,B,T,p) weighted cotangent copy
-            return Plan(_impl(L * B * T * p), "direct",
-                        tuple(zip(("block_d", "block_p"), block_dp(T, d, p))))
+            return _blocked(L * B * T * p, "direct", ("block_d", "block_p"),
+                            block_dp(T, d, p))
         if kind == "emb":
             ids = act_shape if len(act_shape) == 3 else (1,) + tuple(act_shape)
             L, B, T = ids
             d = ds_shape[-1]
-            return Plan(_impl(L * B * T * d), "scatter",
-                        (("block_v", block_v(T, d, vocab)),))
+            return _blocked(L * B * T * d, "scatter", ("block_v",),
+                            block_v(T, d, vocab))
         if kind == "moe":
             a = act_shape if len(act_shape) == 5 else (1,) + tuple(act_shape)
             L, B, E, C, d = a
             p = ds_shape[-1]
-            return Plan(_impl(L * B * E * C * p), "direct",
-                        tuple(zip(("block_d", "block_p"), block_dp(C, d, p))))
+            return _blocked(L * B * E * C * p, "direct", ("block_d", "block_p"),
+                            block_dp(C, d, p))
         raise ValueError(f"unknown tap kind {kind!r}")
 
     return _cached(key, mk)
@@ -378,22 +393,27 @@ def _time(fn, *args, reps: int = 3) -> float:
     return (time.perf_counter() - t0) / reps
 
 
-def autotune(run_fn, candidates, *args) -> tuple:
+def autotune(run_fn, candidates, *args, default: tuple) -> tuple:
     """Measure ``run_fn(*args, **dict(cand))`` per candidate block tuple and
     return the fastest. Call OUTSIDE jit with concrete arrays; feed the
-    winner back via the plan cache (see ``override_blocks``)."""
-    best, best_t, last_err = None, float("inf"), None
+    winner back via the plan cache (see ``override_blocks``).
+
+    ``default`` is the analytic plan's blocks: it runs first, and an error
+    there (a lowering or compile refusal) propagates — it is what the step
+    would run untuned. Any other candidate that fails to lower, compile or
+    run is invalid for this shape and is dropped."""
+    best = tuple(default)
+    best_t = _time(functools.partial(run_fn, **dict(best)), *args)
     for cand in candidates:
+        if tuple(cand) == best:
+            continue
         try:
             t = _time(functools.partial(run_fn, **dict(cand)), *args)
-        except Exception as e:  # candidate invalid for this shape/backend
-            last_err = e
+        except Exception:  # noqa: BLE001 — e.g. a block over the VMEM limit
             continue
         if t < best_t:
-            best, best_t = cand, t
-    if best is None:
-        raise ValueError("no autotune candidate succeeded") from last_err
-    return tuple(best)
+            best, best_t = tuple(cand), t
+    return best
 
 
 def override_blocks(key_prefix: str, kind: str, act_shape, ds_shape,

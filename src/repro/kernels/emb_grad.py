@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import scalar_in_spec
+
 F32 = jnp.float32
 
 
@@ -36,11 +38,11 @@ def _kernel(ids_ref, g_ref, c_ref, out_ref):
 
     bv = out_ref.shape[1]
     v0 = pl.program_id(1) * bv
-    ids = ids_ref[0, 0]                       # (T,) int
+    ids = ids_ref[0, 0]                       # (T, 1) int, column
     g = g_ref[0, 0].astype(F32)               # (T, d)
-    c = c_ref[0].astype(F32)
+    c = c_ref[b]                              # scalar clip factor (SMEM)
     vrange = v0 + jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)
-    onehot = (ids[:, None] == vrange).astype(F32)            # (T, bv)
+    onehot = (ids == vrange).astype(F32)                     # (T, bv)
     tile = jax.lax.dot_general(onehot, g, (((0,), (0,)), ((), ())),
                                preferred_element_type=F32)   # (bv, d)
     out_ref[0] += c * tile
@@ -64,13 +66,14 @@ def emb_clipped_grad(ids, C, ds, vocab: int, block_v: int = 512,
         _kernel,
         grid=(L, nv, B),
         in_specs=[
-            pl.BlockSpec((1, 1, T), lambda l, v, b: (l, b, 0)),
+            # ids as a (T, 1) column: a (1, T) block of (B, T) is refused
+            pl.BlockSpec((1, 1, T, 1), lambda l, v, b: (l, b, 0, 0)),
             pl.BlockSpec((1, 1, T, d), lambda l, v, b: (l, b, 0, 0)),
-            pl.BlockSpec((1,), lambda l, v, b: (b,)),
+            scalar_in_spec(),
         ],
         out_specs=pl.BlockSpec((1, bv, d), lambda l, v, b: (l, v, 0)),
         out_shape=jax.ShapeDtypeStruct((L, V, d), F32),
         interpret=interpret,
-    )(ids, ds, C)
+    )(ids[..., None], ds, C.astype(F32))
     out = out[:, :vocab]
     return out[0] if squeeze else out

@@ -22,6 +22,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ghost_norm import tri_table
+from repro.kernels.layout import scalar_out, scalar_rows
 
 F32 = jnp.float32
 
@@ -34,16 +35,16 @@ def _kernel(ij_ref, ii_ref, jj_ref, gi_ref, gj_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ii = ii_ref[0, 0]                        # (bt,) int ids
-    jj = jj_ref[0, 0]
+    ii = ii_ref[0, 0]                        # (bt, 1) int ids, column
+    jj = jj_ref[0, 0]                        # (1, bt) int ids, row
     gi = gi_ref[0, 0].astype(F32)            # (bt, d)
     gj = gj_ref[0, 0].astype(F32)
-    eq = (ii[:, None] == jj[None, :]).astype(F32)          # (bt, bt) in-register
+    eq = (ii == jj).astype(F32)              # (bt, bt) in-register
     gram_g = jax.lax.dot_general(gi, gj, (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
     contrib = jnp.sum(eq * gram_g)
     scale = jnp.where(ij_ref[k, 0] == ij_ref[k, 1], 1.0, 2.0)
-    out_ref[0] += scale * contrib
+    out_ref[...] += scale * contrib
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -64,21 +65,26 @@ def emb_ghost_norm(ids, ds, block_t: int = 128, interpret: bool = False):
     nt = T // bt
     ij = jnp.asarray(tri_table(nt))
     ntri = ij.shape[0]
+    out_spec, out_shape = scalar_out(B, lambda b, l, k, ij: b)
+    # the ids go in twice, as a column and as a row tile, so that the
+    # indicator is a broadcast compare: a (1, bt) block of a (B, T) array
+    # is refused (second-minor block dim 1), of (B, 1, T) it is whole
+    ids_col, ids_row = ids[..., None], ids[..., None, :]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, L, ntri),
         in_specs=[
-            pl.BlockSpec((1, 1, bt), lambda b, l, k, ij: (l, b, ij[k, 0])),
-            pl.BlockSpec((1, 1, bt), lambda b, l, k, ij: (l, b, ij[k, 1])),
+            pl.BlockSpec((1, 1, bt, 1), lambda b, l, k, ij: (l, b, ij[k, 0], 0)),
+            pl.BlockSpec((1, 1, 1, bt), lambda b, l, k, ij: (l, b, 0, ij[k, 1])),
             pl.BlockSpec((1, 1, bt, d), lambda b, l, k, ij: (l, b, ij[k, 0], 0)),
             pl.BlockSpec((1, 1, bt, d), lambda b, l, k, ij: (l, b, ij[k, 1], 0)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, l, k, ij: (b,)),
+        out_specs=out_spec,
     )
-    return pl.pallas_call(
+    return scalar_rows(pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), F32),
+        out_shape=out_shape,
         interpret=interpret,
-    )(ij, ids, ids, ds, ds)
+    )(ij, ids_col, ids_row, ds, ds))

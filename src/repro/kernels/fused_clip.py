@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import scalar_in_spec, scalar_out, scalar_rows
+
 F32 = jnp.float32
 
 
@@ -46,8 +48,8 @@ def _kernel(a_ref, g_ref, w_ref, out_ref, sq_ref, *, clip):
     g = jax.lax.dot_general(a, ds, (((1,), (1,)), ((0,), (0,))),
                             preferred_element_type=F32)     # (L, d, p)
     sq = jnp.sum(g * g)
-    c = clip(jnp.sqrt(sq)).astype(F32) * w_ref[0].astype(F32)
-    sq_ref[0] = sq
+    c = clip(jnp.sqrt(sq)).astype(F32) * w_ref[b]
+    sq_ref[...] = jnp.broadcast_to(sq, sq_ref.shape)
     out_ref[...] += c * g
 
 
@@ -77,6 +79,7 @@ def fused_clip_grad(a, ds, w, clipping: str, R: float, gamma: float,
     if pp_:
         ds = jnp.pad(ds, ((0, 0), (0, 0), (0, 0), (0, pp_)))
     D, P = a.shape[-1], ds.shape[-1]
+    sq_spec, sq_shape = scalar_out(B, lambda b: b)
 
     out, sq = pl.pallas_call(
         functools.partial(_kernel, clip=clip),
@@ -84,17 +87,17 @@ def fused_clip_grad(a, ds, w, clipping: str, R: float, gamma: float,
         in_specs=[
             pl.BlockSpec((L, 1, T, D), lambda b: (0, b, 0, 0)),
             pl.BlockSpec((L, 1, T, P), lambda b: (0, b, 0, 0)),
-            pl.BlockSpec((1,), lambda b: (b,)),
+            scalar_in_spec(),
         ],
         out_specs=[
             pl.BlockSpec((L, D, P), lambda b: (0, 0, 0)),
-            pl.BlockSpec((1,), lambda b: (b,)),
+            sq_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((L, D, P), F32),
-            jax.ShapeDtypeStruct((B,), F32),
+            sq_shape,
         ],
         interpret=interpret,
     )(a, ds, w.astype(F32))
     out = out[:, :d, :p]
-    return (out[0] if squeeze else out), sq
+    return (out[0] if squeeze else out), scalar_rows(sq)

@@ -27,6 +27,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import scalar_out, scalar_rows
+
 F32 = jnp.float32
 
 
@@ -55,7 +57,7 @@ def _kernel(ij_ref, ai_ref, aj_ref, gi_ref, gj_ref, out_ref):
                                  preferred_element_type=F32)
     contrib = jnp.sum(gram_a * gram_g)
     scale = jnp.where(ij_ref[k, 0] == ij_ref[k, 1], 1.0, 2.0)
-    out_ref[0] += scale * contrib
+    out_ref[...] += scale * contrib
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -74,6 +76,7 @@ def ghost_norm(a, ds, block_t: int = 128, interpret: bool = False):
     nt = T // bt
     ij = jnp.asarray(tri_table(nt))
     ntri = ij.shape[0]
+    out_spec, out_shape = scalar_out(B, lambda b, l, k, ij: b)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -84,11 +87,11 @@ def ghost_norm(a, ds, block_t: int = 128, interpret: bool = False):
             pl.BlockSpec((1, 1, bt, p), lambda b, l, k, ij: (l, b, ij[k, 0], 0)),
             pl.BlockSpec((1, 1, bt, p), lambda b, l, k, ij: (l, b, ij[k, 1], 0)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, l, k, ij: (b,)),
+        out_specs=out_spec,
     )
-    return pl.pallas_call(
+    return scalar_rows(pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B,), F32),
+        out_shape=out_shape,
         interpret=interpret,
-    )(ij, a, a, ds, ds)
+    )(ij, a, a, ds, ds))

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import scalar_out, scalar_rows
+
 F32 = jnp.float32
 
 
@@ -35,7 +37,7 @@ def _kernel(a_ref, g_ref, out_ref):
     g = g_ref[0, 0].astype(F32)              # (T, bp)
     tile = jax.lax.dot_general(a, g, (((0,), (0,)), ((), ())),
                                preferred_element_type=F32)  # (bd, bp)
-    out_ref[0] += jnp.sum(tile * tile)
+    out_ref[...] += jnp.sum(tile * tile)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "block_p", "interpret"))
@@ -53,6 +55,7 @@ def grad_norm_direct(a, ds, block_d: int = 256, block_p: int = 256,
     if p % bp:
         ds = jnp.pad(ds, ((0, 0), (0, 0), (0, 0), (0, bp - p % bp)))
         p = ds.shape[-1]
+    out_spec, out_shape = scalar_out(B, lambda b, l, i, j: b)
 
     out = pl.pallas_call(
         _kernel,
@@ -61,8 +64,8 @@ def grad_norm_direct(a, ds, block_d: int = 256, block_p: int = 256,
             pl.BlockSpec((1, 1, T, bd), lambda b, l, i, j: (l, b, 0, i)),
             pl.BlockSpec((1, 1, T, bp), lambda b, l, i, j: (l, b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, l, i, j: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), F32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(a, ds)
-    return out
+    return scalar_rows(out)

@@ -14,7 +14,9 @@ module 3/4/5 fusion to the expert-parallel layout.
   moe_clipped_grad  G_le = sum_b C_b a_be^T dm_be            grid (L,E,nd,np,B)
 
 Capacity C is small by construction (T * capacity_factor * top_k / E), so the
-(C,*) blocks are kept whole; only d/p are tiled.
+(C,*) blocks are kept whole; only d/p are tiled. The mask goes in as a
+(C, 1) column per (sample, expert): a (1, C) block of (..., E, C) is refused
+by the TPU lowering (second-minor block dim 1 of E).
 """
 from __future__ import annotations
 
@@ -24,14 +26,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.layout import scalar_in_spec, scalar_out, scalar_rows
+
 F32 = jnp.float32
 
 
 def _moe5(a, mask, ds):
     if a.ndim == 4:
-        return a[None], mask[None], ds[None], True
+        return a[None], mask[None, ..., None], ds[None], True
     if a.ndim == 5:
-        return a, mask, ds, False
+        return a, mask[..., None], ds, False
     raise ValueError(f"moe record must be 4D or 5D, got {a.shape}")
 
 
@@ -44,14 +48,14 @@ def _ghost_kernel(a_ref, m_ref, g_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    m = m_ref[0, 0, 0].astype(F32)                    # (C,)
-    am = a_ref[0, 0, 0].astype(F32) * m[:, None]      # (C, d)
-    dm = g_ref[0, 0, 0].astype(F32) * m[:, None]      # (C, p)
+    m = m_ref[0, 0, 0].astype(F32)                    # (C, 1)
+    am = a_ref[0, 0, 0].astype(F32) * m               # (C, d)
+    dm = g_ref[0, 0, 0].astype(F32) * m               # (C, p)
     gram_a = jax.lax.dot_general(am, am, (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
     gram_g = jax.lax.dot_general(dm, dm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=F32)
-    out_ref[0] += jnp.sum(gram_a * gram_g)
+    out_ref[...] += jnp.sum(gram_a * gram_g)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -60,19 +64,20 @@ def moe_ghost_norm(a, mask, ds, interpret: bool = False):
     a, mask, ds, _ = _moe5(a, mask, ds)
     L, B, E, C, d = a.shape
     p = ds.shape[-1]
+    out_spec, out_shape = scalar_out(B, lambda b, l, e: b)
     out = pl.pallas_call(
         _ghost_kernel,
         grid=(B, L, E),
         in_specs=[
             pl.BlockSpec((1, 1, 1, C, d), lambda b, l, e: (l, b, e, 0, 0)),
-            pl.BlockSpec((1, 1, 1, C), lambda b, l, e: (l, b, e, 0)),
+            pl.BlockSpec((1, 1, 1, C, 1), lambda b, l, e: (l, b, e, 0, 0)),
             pl.BlockSpec((1, 1, 1, C, p), lambda b, l, e: (l, b, e, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, l, e: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), F32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(a, mask, ds)
-    return out
+    return scalar_rows(out)
 
 
 # ------------------------------------------------------------ direct norm
@@ -86,12 +91,12 @@ def _direct_kernel(a_ref, m_ref, g_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    m = m_ref[0, 0, 0].astype(F32)                    # (C,)
+    m = m_ref[0, 0, 0].astype(F32)                    # (C, 1)
     a = a_ref[0, 0, 0].astype(F32)                    # (C, bd)
-    dm = g_ref[0, 0, 0].astype(F32) * m[:, None]      # (C, bp)
+    dm = g_ref[0, 0, 0].astype(F32) * m               # (C, bp)
     tile = jax.lax.dot_general(a, dm, (((0,), (0,)), ((), ())),
                                preferred_element_type=F32)
-    out_ref[0] += jnp.sum(tile * tile)
+    out_ref[...] += jnp.sum(tile * tile)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "block_p", "interpret"))
@@ -108,21 +113,23 @@ def moe_direct_norm(a, mask, ds, block_d: int = 256, block_p: int = 256,
     if p % bp:
         ds = jnp.pad(ds, ((0, 0),) * 4 + ((0, bp - p % bp),))
         p = ds.shape[-1]
+    out_spec, out_shape = scalar_out(B, lambda b, l, e, i, j: b)
     out = pl.pallas_call(
         _direct_kernel,
         grid=(B, L, E, d // bd, p // bp),
         in_specs=[
             pl.BlockSpec((1, 1, 1, C, bd),
                          lambda b, l, e, i, j: (l, b, e, 0, i)),
-            pl.BlockSpec((1, 1, 1, C), lambda b, l, e, i, j: (l, b, e, 0)),
+            pl.BlockSpec((1, 1, 1, C, 1),
+                         lambda b, l, e, i, j: (l, b, e, 0, 0)),
             pl.BlockSpec((1, 1, 1, C, bp),
                          lambda b, l, e, i, j: (l, b, e, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1,), lambda b, l, e, i, j: (b,)),
-        out_shape=jax.ShapeDtypeStruct((B,), F32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(a, mask, ds)
-    return out
+    return scalar_rows(out)
 
 
 # ----------------------------------------------------------- clipped grad
@@ -133,10 +140,10 @@ def _grad_kernel(a_ref, m_ref, g_ref, c_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    m = m_ref[0, 0, 0].astype(F32)                    # (C,)
+    m = m_ref[0, 0, 0].astype(F32)                    # (C, 1)
     a = a_ref[0, 0, 0].astype(F32)                    # (C, bd)
-    dm = g_ref[0, 0, 0].astype(F32) * m[:, None]      # (C, bp)
-    c = c_ref[0].astype(F32)
+    dm = g_ref[0, 0, 0].astype(F32) * m               # (C, bp)
+    c = c_ref[b]                                      # clip factor (SMEM)
     tile = jax.lax.dot_general(a * c, dm, (((0,), (0,)), ((), ())),
                                preferred_element_type=F32)
     out_ref[0, 0] += tile
@@ -162,15 +169,16 @@ def moe_clipped_grad(a, mask, C, ds, block_d: int = 256, block_p: int = 256,
         in_specs=[
             pl.BlockSpec((1, 1, 1, Cap, bd),
                          lambda l, e, i, j, b: (l, b, e, 0, i)),
-            pl.BlockSpec((1, 1, 1, Cap), lambda l, e, i, j, b: (l, b, e, 0)),
+            pl.BlockSpec((1, 1, 1, Cap, 1),
+                         lambda l, e, i, j, b: (l, b, e, 0, 0)),
             pl.BlockSpec((1, 1, 1, Cap, bp),
                          lambda l, e, i, j, b: (l, b, e, 0, j)),
-            pl.BlockSpec((1,), lambda l, e, i, j, b: (b,)),
+            scalar_in_spec(),
         ],
         out_specs=pl.BlockSpec((1, 1, bd, bp),
                                lambda l, e, i, j, b: (l, e, i, j)),
         out_shape=jax.ShapeDtypeStruct((L, E, D, P), F32),
         interpret=interpret,
-    )(a, mask, ds, C)
+    )(a, mask, ds, C.astype(F32))
     out = out[:, :, :d, :p]
     return out[0] if squeeze else out

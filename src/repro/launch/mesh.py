@@ -11,17 +11,22 @@ touch jax device state — the dry-run sets XLA_FLAGS before first jax use.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the engine's ``shard_map``
+    calls and ``in/out_shardings`` leave propagation to GSPMD. (Since JAX
+    0.9 the default is ``Explicit``, under which the embedding gather of a
+    batch-sharded id array raises ``ShardingTypeError``.)"""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(shape=(1, 1), axes=("data", "model")):
-    """Degenerate mesh for CPU tests."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_train_mesh(data: int = 0, model: int = 1):
@@ -41,8 +46,8 @@ def make_train_mesh(data: int = 0, model: int = 1):
     if data * model > n:
         raise ValueError(f"mesh ({data}, {model}) needs {data * model} "
                          f"devices, have {n}")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:data * model])
+    return make_mesh((data, model), ("data", "model"),
+                     devices=jax.devices()[:data * model])
 
 
 def batch_axes(mesh) -> tuple:

@@ -151,7 +151,7 @@ class CellPlan:
                 mesh = sh.mesh
                 break
         if mesh is not None:
-            with mesh:  # Mesh is the context manager (jax.set_mesh is newer)
+            with jax.set_mesh(mesh):
                 return self.jitted().lower(*self.args)
         return self.jitted().lower(*self.args)
 

@@ -17,8 +17,13 @@ horizon), ``--restart-every N`` restarts both the optimizer anchor and the
 noise tree every N steps, and ``--tree-completion`` applies the
 honest-restart variance correction at each boundary.
 
-Runs on whatever devices exist (CPU here, a pod via the same pjit path on
-TPU — pass --mesh data,model sizes)."""
+Runs on whatever devices exist: the CPU for tests (Pallas kernels in
+interpret mode), a TPU chip or host with the kernels compiled by Mosaic
+(pass --mesh data,model sizes). ``chip_smoke.py`` at the checkout root is
+the end-to-end check on a chip.
+
+JAX's persistent compile cache lives where ``JAX_COMPILATION_CACHE_DIR``
+says, and otherwise at ``<checkout>/.jax_cache`` (``use_compile_cache``)."""
 from __future__ import annotations
 
 import argparse
@@ -26,6 +31,9 @@ import dataclasses
 import functools
 import json
 import math
+import os
+import pathlib
+import sys
 import time
 
 import jax
@@ -51,6 +59,22 @@ from repro.runtime.fault_injection import maybe_fault
 from repro.runtime.fault_tolerance import (CheckpointManager, Heartbeat,
                                            PreemptionGuard)
 from repro.utils.tree import flatten
+
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """-> the persistent compile cache directory ('' when off). JAX reads
+    ``JAX_COMPILATION_CACHE_DIR`` itself; without it an accelerator's
+    cache goes to one fixed path in the checkout, so that a rerun of the
+    same program finds what the last one compiled. CPU programs are not
+    cached: their entries are tied to the host's CPU features."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path and jax.default_backend() != "cpu":
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def resolve_dp(arch: str, policy_name: str, mode: str, clipping: str,
@@ -173,11 +197,7 @@ def autotune_warmup(apply_fn, params, batch, dp, log=print) -> int:
                 fn = functools.partial(fn, vocab=vocab)  # static under jit
             knobs = tuple(name for name, _ in plan.blocks)
             run = jax.jit(fn, static_argnames=knobs)
-            try:
-                best = dispatch.autotune(run, cands, *args)
-            except ValueError as e:
-                log(f"autotune {key}/{phase}: no candidate ran ({e})")
-                continue
+            best = dispatch.autotune(run, cands, *args, default=plan.blocks)
             dispatch.override_blocks(phase, kind, a_struct.shape,
                                      ds_struct.shape, best,
                                      mode=policy.mode, vocab=vocab,
@@ -320,7 +340,9 @@ def train(model_cfg, tc: TrainConfig, dp, log=print,
         log(report.describe() + "; requesting graceful stop + checkpoint")
         guard.request_stop()
 
-    hb = Heartbeat(timeout_s=600.0, on_stall=on_stall)
+    # the watchdog starts after the first step has run: autotune and the
+    # step's first compile come before it and may take longer than timeout_s
+    hb = None
     mgr = (CheckpointManager(tc.checkpoint_dir, every=tc.checkpoint_every,
                              keep=tc.keep_checkpoints)
            if tc.checkpoint_dir else None)
@@ -381,6 +403,15 @@ def train(model_cfg, tc: TrainConfig, dp, log=print,
     # absolute step into it, so restoring it replays the interrupted run's
     # exact noise sequence (the bitwise-restart guarantee)
     state = init_train_state(params, opt_state, start, base_rng, state_sh)
+    # compiled ahead of the loop: the compile is timed apart from the steps,
+    # and the program can be checked for its Pallas kernels
+    t0 = time.time()
+    with mesh:
+        compiled = jitted.lower(
+            state, jax.device_put(pipe.batch(start), batch_sh)).compile()
+    n_kernels = compiled.as_text().count("tpu_custom_call")
+    log(f"step compiled in {time.time() - t0:.1f}s "
+        f"({n_kernels} Pallas kernel calls)")
 
     def snapshot(s: TrainState, step: int) -> dict:
         return {"params": s.params, "opt": s.opt_state,
@@ -411,8 +442,10 @@ def train(model_cfg, tc: TrainConfig, dp, log=print,
         for step in range(start, tc.steps):
             maybe_fault("step", step)  # crash/preemption injection (tests)
             batch = jax.device_put(pipe.batch(step), batch_sh)
-            state, loss = jitted(state, batch)
+            state, loss = compiled(state, batch)
             pending.append(loss)
+            if hb is None:
+                hb = Heartbeat(timeout_s=600.0, on_stall=on_stall)
             hb.beat(step)
             # every executed absolute step is accounted exactly once —
             # resumed replays are no-ops (ledger.record_to is idempotent)
@@ -425,14 +458,17 @@ def train(model_cfg, tc: TrainConfig, dp, log=print,
                     mgr.maybe_save(step, snapshot(state, step), force=True,
                                    meta=run_meta())
                 flush(step)
-                log(f"preempted at step {step}; checkpoint saved")
+                log(f"{'preempted' if guard.signalled else 'stopped'} at "
+                    f"step {step}"
+                    + ("; checkpoint saved" if mgr is not None else ""))
                 break
             if (step + 1) % log_every == 0 or step == tc.steps - 1:
                 flush(step)
     flush(tc.steps - 1)
     if mgr is not None:
         mgr.wait()
-    hb.close()
+    if hb is not None:
+        hb.close()
 
     epsilon = None
     if final_policy.mode != "nonprivate" and ledger.recorded_to > 0:
@@ -443,6 +479,8 @@ def train(model_cfg, tc: TrainConfig, dp, log=print,
     if summary_out is not None:
         summary_out.update({
             "steps_done": ledger.recorded_to,
+            "preempted": guard.signalled,
+            "step_kernel_calls": n_kernels,
             "resumed_from": start,
             "epsilon": epsilon,
             "delta": delta,
@@ -516,6 +554,7 @@ def main():
                          "params sha256, ledger) — the CI crash/resume "
                          "stage compares these across runs")
     args = ap.parse_args()
+    use_compile_cache()
 
     mesh_data, mesh_model = 0, 1
     if args.mesh:
@@ -545,13 +584,17 @@ def main():
                      checkpoint_every=args.ckpt_every)
     dp = resolve_dp(args.arch, args.policy, args.mode, args.clipping,
                     args.sigma)
-    summary = {} if args.out else None
+    summary = {}
     train(mc, tc, dp, dataset_size=args.dataset_size,
           target_epsilon=args.epsilon, summary_out=summary)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(summary, f, indent=2)
         print(f"summary written to {args.out}")
+    if summary["steps_done"] < tc.steps and not summary["preempted"]:
+        # only a real preemption (SIGTERM) may end a run early with status 0
+        sys.exit(f"stopped after {summary['steps_done']} of {tc.steps} "
+                 "steps without a preemption signal")
 
 
 if __name__ == "__main__":

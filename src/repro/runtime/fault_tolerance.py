@@ -25,10 +25,13 @@ from repro.checkpoint import checkpoint as ckpt
 
 class PreemptionGuard:
     """Installs a SIGTERM/SIGINT handler that flips a flag; the train loop
-    polls should_stop() once per step and checkpoints before exiting."""
+    polls should_stop() once per step and checkpoints before exiting.
+    ``signalled`` tells a real preemption (the signal) from a stop the
+    program requested itself (a stalled heartbeat)."""
 
     def __init__(self, install: bool = True):
         self._stop = threading.Event()
+        self.signalled = False
         if install:
             try:
                 signal.signal(signal.SIGTERM, self._handler)
@@ -36,6 +39,7 @@ class PreemptionGuard:
                 pass
 
     def _handler(self, signum, frame):
+        self.signalled = True
         self._stop.set()
 
     def request_stop(self):

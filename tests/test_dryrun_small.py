@@ -18,10 +18,11 @@ CODE = textwrap.dedent("""
     from unittest import mock
     from repro.configs.base import SHAPES, ShapeConfig
     from repro.configs import registry
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import plan_cell
     from repro.utils.hlo import collective_bytes
 
-    mesh = jax.make_mesh({mesh_shape}, {mesh_axes})
+    mesh = make_mesh({mesh_shape}, {mesh_axes})
     # shrink the configs + shapes so CPU compiles in seconds
     small = registry.smoke_config("{arch}").with_(name="{arch}", remat=False,
                                                   attn_chunk=0)

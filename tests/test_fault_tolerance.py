@@ -37,6 +37,46 @@ def test_preemption_guard_request_stop_without_signal():
     assert guard.should_stop()
 
 
+def test_preemption_guard_tells_signal_from_request():
+    old = signal.getsignal(signal.SIGTERM)
+    try:
+        guard = PreemptionGuard(install=True)
+        guard.request_stop()
+        assert guard.should_stop() and not guard.signalled
+        os.kill(os.getpid(), signal.SIGTERM)
+        assert guard.signalled
+    finally:
+        signal.signal(signal.SIGTERM, old)
+
+
+def test_train_cli_fails_when_stopped_without_preemption(monkeypatch):
+    """A stall stop (no SIGTERM) ends the CLI non-zero after saving; the
+    heartbeat is built only once the first step has run."""
+    from repro.launch import train as train_mod
+
+    built = []
+
+    class StallAtOnce:
+        def __init__(self, timeout_s, on_stall):
+            built.append(timeout_s)
+            self.on_stall = on_stall
+
+        def beat(self, step):
+            self.on_stall(StallReport(step, 601.0, 600.0, "cpu"))
+
+        def close(self):
+            pass
+
+    monkeypatch.setattr(train_mod, "Heartbeat", StallAtOnce)
+    monkeypatch.setattr("sys.argv", [
+        "train", "--arch", "qwen2-1.5b", "--smoke", "--steps", "3",
+        "--batch", "2", "--seq", "8", "--autotune", "off"])
+    with pytest.raises(SystemExit) as exc:
+        train_mod.main()
+    assert exc.value.code and "1 of 3 steps" in str(exc.value.code)
+    assert built == [600.0]
+
+
 def test_preemption_guard_off_main_thread_is_safe():
     """Installing from a non-main thread must not raise (signal.signal does);
     request_stop still works."""

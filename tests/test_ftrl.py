@@ -20,6 +20,7 @@ from repro.core.noise import (GaussianMechanism, TreeAggregationMechanism,
                               add_noise, get_mechanism, next_pow2)
 from repro.core.policy import (ParamGroup, PrivacyPolicy, finalize_noise,
                                resolve_policy)
+from repro.launch.mesh import make_mesh
 from repro.optim.optimizers import make_optimizer
 
 
@@ -358,7 +359,7 @@ def test_plan_cell_threads_registered_policy(monkeypatch):
 
     small = registry.smoke_config("deepseek-moe-16b").with_(
         name="deepseek-moe-16b", remat=False, attn_chunk=0)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with mock.patch.object(steps_mod, "get_config", lambda n: small), \
          mock.patch.dict(SHAPES, {"train_4k": ShapeConfig("train_4k", 16, 8,
                                                           "train")}), \

@@ -213,6 +213,36 @@ def test_dispatch_blocks_respect_vmem_budget():
     assert 4 * (1024 * bv + bv * 768 + 1024 * 768) <= dispatch.VMEM_BUDGET
 
 
+def test_dispatch_blocks_are_lane_aligned_or_absent():
+    # lane dims of a block are multiples of 128 or the whole dim; when no
+    # block fits the budget the plan is the jnp path, never an oversize one
+    assert dispatch.block_dp(512, 1536, 8960) == (512, 512)
+    assert dispatch.block_dp(512, 96, 200) == (96, 200)
+    assert dispatch.block_dp(1 << 16, 4096, 4096) is None
+    assert dispatch.block_t_ghost(512, 1536, 151936) == 0
+    assert dispatch.block_t_ghost(100, 64, 64, lane=True) == 104
+    assert dispatch.block_t_ghost(2048, 1536, 1536, lane=True) % 128 == 0
+    head = dispatch.norm_plan("mm", (4, 512, 1536), (4, 512, 151936), "bk")
+    assert (head.impl, head.method, head.blocks) == ("jnp", "ghost", ())
+
+
+def test_autotune_raises_for_the_analytic_blocks_only():
+    def run(x, block=0):
+        if block == 64:
+            raise ValueError("refused by the compiler")
+        return x * block
+
+    x = jnp.ones(4)
+    # a failing non-default candidate is dropped ...
+    best = dispatch.autotune(run, [(("block", 64),), (("block", 8),)], x,
+                             default=(("block", 8),))
+    assert best == (("block", 8),)
+    # ... but the analytic choice failing is an error, not a skip
+    with pytest.raises(ValueError, match="refused"):
+        dispatch.autotune(run, [(("block", 8),)], x,
+                          default=(("block", 64),))
+
+
 def test_dispatch_layerwise_rule_matches_ghost_module():
     # long-T conv-style record -> direct; short-T wide layer -> ghost
     assert dispatch.norm_plan("mm", (4, 4096, 32, 32),

@@ -32,6 +32,7 @@ PARITY = textwrap.dedent("""
     from repro.configs.registry import build, smoke_config
     from repro.core.bk import DPConfig
     from repro.data.pipeline import Pipeline, PipelineConfig
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import TrainState, make_train_step
     from repro.optim.optimizers import make_optimizer
     from repro.utils.tree import flatten
@@ -67,8 +68,8 @@ PARITY = textwrap.dedent("""
             state, loss = jitted(state, batch)
         return jax.device_get(state.params), float(loss)
 
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+    mesh8 = make_mesh((4, 2), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"),
                           devices=jax.devices()[:1])
     for mb in (0, 4):   # full batch AND the microbatch lax.scan path
         p8, l8 = run(mesh8, mb)
@@ -98,8 +99,9 @@ NOISE_HLO = textwrap.dedent("""
     from repro.core.bk import DPConfig
     from repro.core.policy import as_policy, finalize_noise, resolve_policy
     from repro.launch import sharding as sh
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     # 'head/w' shards ('data','model') -> per-device slice (16, 24)
     params = {"head": {"w": jnp.zeros((64, 48))}}
     pspecs = sh.flat_param_pspecs(params, mesh)
@@ -146,6 +148,7 @@ NOISE_DEVCOUNT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.core.noise import counter_normal, sharded_normal
+    from repro.launch.mesh import make_mesh
 
     rng = jax.random.PRNGKey(5)
     shape = (64, 32)
@@ -155,7 +158,7 @@ NOISE_DEVCOUNT = textwrap.dedent("""
     ref = np.asarray(counter_normal(rng, shape))
     assert abs(ref.mean()) < 0.1 and abs(ref.std() - 1.0) < 0.1, ref.std()
     for nd in (1, 2, 8):
-        mesh = jax.make_mesh((nd, 1), ("data", "model"),
+        mesh = make_mesh((nd, 1), ("data", "model"),
                              devices=jax.devices()[:nd])
         x = np.asarray(sharded_normal(rng, shape, mesh=mesh,
                                       spec=P("data", None)))
@@ -163,12 +166,12 @@ NOISE_DEVCOUNT = textwrap.dedent("""
         # at every device count (not merely statistically matched)
         np.testing.assert_array_equal(x, ref, err_msg=str(nd))
     # sharding BOTH dims on a 2-D mesh still assembles the same tensor
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     x = np.asarray(sharded_normal(rng, shape, mesh=mesh,
                                   spec=P("data", "model")))
     np.testing.assert_array_equal(x, ref)
     # non-divisible dims fall back (same values, GSPMD-partitioned)
-    z = sharded_normal(rng, (63, 32), mesh=jax.make_mesh(
+    z = sharded_normal(rng, (63, 32), mesh=make_mesh(
         (8, 1), ("data", "model")), spec=P("data", None))
     assert z.shape == (63, 32)
     np.testing.assert_array_equal(np.asarray(z),
@@ -182,6 +185,23 @@ def test_shard_local_noise_bitwise_portable_across_device_counts():
     shards (and 2-D meshes) are bitwise identical, so sigma>0 runs are
     mesh-portable; non-divisible dims fall back to the same values."""
     _run(NOISE_DEVCOUNT)
+
+
+def test_normal_from_bits_is_finite_and_symmetric_at_the_extremes():
+    """Every uint32 maps to a finite normal, in order: the top counter cell
+    once rounded to u = 1.0 and drew +inf (about 40 times per step on a
+    0.65 B param model)."""
+    import jax.numpy as jnp
+    from jax.scipy.special import ndtri
+
+    from repro.core.noise import normal_from_bits
+
+    bits = jnp.array([0, 255, 256, 0x7FFFFFFF, 0x80000000, 0xFFFFFE00,
+                      0xFFFFFF00, 0xFFFFFFFF], jnp.uint32)
+    z = np.asarray(normal_from_bits(bits))
+    assert np.isfinite(z).all(), z
+    assert (np.diff(z) >= 0).all() and z[0] == z[1] < z[2] and z[0] < -5.0
+    assert z[-1] == z[-2] == float(ndtri(jnp.float32(1 - 2 ** -24))) > 5.0
 
 
 def test_counter_normal_wide_counter_consistency():
@@ -217,6 +237,7 @@ PADDED = textwrap.dedent("""
     from repro.configs.registry import build, smoke_config
     from repro.core.bk import DPConfig, bk_private_grad, pad_batch
     from repro.data.pipeline import Pipeline, PipelineConfig
+    from repro.launch.mesh import make_mesh
     from repro.utils.tree import flatten
 
     cfg = smoke_config("qwen2-1.5b").with_(dtype="float32",
@@ -228,8 +249,8 @@ PADDED = textwrap.dedent("""
     pipe = Pipeline(cfg, PipelineConfig(6, 16, seed=0))
     batch = pipe.batch(0)
     dp = DPConfig(mode="bk-mixopt", sigma=0.0)
-    mesh8 = jax.make_mesh((4, 2), ("data", "model"))
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+    mesh8 = make_mesh((4, 2), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"),
                           devices=jax.devices()[:1])
 
     padded, mask, Bp = pad_batch(batch, mesh8, 6)

@@ -166,13 +166,13 @@ def test_compressed_allreduce_multidevice_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, numpy as np, jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.runtime.compression import compressed_allreduce_mean
         mesh = Mesh(np.array(jax.devices()).reshape(4), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 128))
         rngs = jax.random.split(jax.random.PRNGKey(1), 4)
-        f = shard_map(lambda xs, rs: compressed_allreduce_mean(xs[0], rs[0], "pod")[None],
-                      mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=P("pod"))
+        f = jax.shard_map(
+            lambda xs, rs: compressed_allreduce_mean(xs[0], rs[0], "pod")[None],
+            mesh=mesh, in_specs=(P("pod"), P("pod")), out_specs=P("pod"))
         got = f(x, rngs)
         want = jnp.mean(x, axis=0)
         scale = float(jnp.max(jnp.abs(x))) / 127.0

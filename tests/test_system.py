@@ -10,6 +10,7 @@ import pytest
 from repro.configs.base import TrainConfig
 from repro.configs.registry import build, smoke_config
 from repro.core.bk import DPConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.train import train
 
 
@@ -109,7 +110,7 @@ def test_sharding_rules_sanitize():
     import numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.launch.sharding import sanitize, spec_for
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     class FakeMesh:
         shape = {"data": 16, "model": 16, "pod": 2}
