@@ -93,13 +93,16 @@ def _unit_weighted_grads(apply_fn, params, batch, res, unit_C):
 # ----------------------------------------------------------------- baselines
 def nonprivate_grad(apply_fn, params, batch, rng, cfg, step=None,
                     mesh=None, pspecs=None):
+    """The plain mean gradient, its forward and backward in the
+    ``jax.named_scope`` ``grad``."""
     policy = as_policy(cfg)
     res = resolve_policy(policy, flatten(params))
 
     def mean_loss(p):
         return jnp.mean(_loss_all(apply_fn, p, batch))
 
-    loss, grads = jax.value_and_grad(mean_loss)(params)
+    with jax.named_scope("grad"):
+        loss, grads = jax.value_and_grad(mean_loss)(params)
     if res.frozen:  # policies freeze groups even without clipping/noise
         flat = flatten(grads)
         for p in res.frozen:

@@ -16,6 +16,11 @@ Phases (all inside one jit-able pure function):
   3. weighted gradients G_l = a^T diag(C) ds  — module 2b'/5
   4. Gaussian noise, scale by 1/B
 
+Phases 1-3 run inside the ``jax.named_scope`` ``bk_taps``, ``bk_norms`` and
+``bk_clipped_sum``, and each tap's work in phases 2-3 inside one named by
+its key (``tap_scope``): compile-time names that reach the profiler's op
+paths and add no ops.
+
 Modes:
   'bk'           ghost norm everywhere (base BK)
   'bk-mixghost'  layerwise ghost-vs-direct for the *norm* only
@@ -137,6 +142,13 @@ def tap_act_structs(apply_fn, params, batch):
 
 def _tap_w(key: str) -> str:
     return parse_key(key)[0] + "/w"
+
+
+def tap_scope(key: str):
+    """The profiler scope of one tap's norm or weighted-gradient work: the
+    tap key with '/' replaced by '.', so that it stays one segment of the
+    op path."""
+    return jax.named_scope(key.replace("/", "."))
 
 
 def split_param_paths(params, tap_struct):
@@ -578,11 +590,12 @@ def bk_clipped_sum(apply_fn, params, batch, cfg, mesh=None, rng=None):
         lsum = jnp.sum(losses * mask) if mask is not None else jnp.sum(losses)
         return lsum, (losses, tape.acts)
 
-    loss_sum, jvp_fn, (losses, stored_acts) = jax.linearize(
-        run, taps0, psp0, has_aux=True)
-    transpose = jax.linear_transpose(lambda dt, dp: jvp_fn(dt, dp),
-                                     taps0, psp0)
-    ds_taps, g_psp = transpose(jnp.ones_like(loss_sum))
+    with jax.named_scope("bk_taps"):
+        loss_sum, jvp_fn, (losses, stored_acts) = jax.linearize(
+            run, taps0, psp0, has_aux=True)
+        transpose = jax.linear_transpose(lambda dt, dp: jvp_fn(dt, dp),
+                                         taps0, psp0)
+        ds_taps, g_psp = transpose(jnp.ones_like(loss_sum))
 
     # ---- phase 2: per-unit per-sample norms + clip factors; each cotangent
     # is consumed by its norm as produced, then held per its tape policy.
@@ -594,9 +607,10 @@ def bk_clipped_sum(apply_fn, params, batch, cfg, mesh=None, rng=None):
     # and the record is dead the moment the grad is emitted. ----
     from repro.kernels import dispatch
     unit_of = lambda p: res.unit_of[p]
-    sq = [jnp.zeros((B,), F32) for _ in res.units]
     held, cache, acts_l, flat_grads = {}, {}, {}, {}
-    for key in active_taps:
+
+    def tap_norm(key, sq):
+        """Phase 2 at one tap: its squared norms added into ``sq``."""
         wpath = _tap_w(key)
         pol = tape_pol[key]
         # bf16 records feed the consumers AS STORED: every norm/grad path
@@ -663,7 +677,7 @@ def bk_clipped_sum(apply_fn, params, batch, cfg, mesh=None, rng=None):
                 flat_grads[wpath] = record_weighted_grad(
                     key, act, ds, C_u, cached, policy.use_kernels, w.dtype,
                     vocab, mesh=mesh, shard=shard)
-            continue
+            return
         acts_l[key] = act
         nk, cached = record_sq_norm(key, acts_l[key], ds_taps[key],
                                     policy.mode, policy.use_kernels,
@@ -677,89 +691,100 @@ def bk_clipped_sum(apply_fn, params, batch, cfg, mesh=None, rng=None):
                                   if pol == "int8" else None))
         u = unit_of(wpath)
         sq[u] = sq[u] + nk
-    for p in psp_active:
-        g = g_psp[p].astype(F32)
-        u = unit_of(p)
-        sq[u] = sq[u] + jnp.sum(g * g, axis=tuple(range(1, g.ndim)))
-    if shard:
-        # the (B,) accumulators (and the clip factors derived from them)
-        # reduce locally at size B_local and STAY sharded into phase 3
-        sq = [_constrain(s, mesh, P(ba)) for s in sq]
-    unit_norms, unit_C = unit_clip_factors(res, sq)
-    if mask is not None:
-        unit_C = [c * mask for c in unit_C]
+
+    with jax.named_scope("bk_norms"):
+        sq = [jnp.zeros((B,), F32) for _ in res.units]
+        for key in active_taps:
+            with tap_scope(key):
+                tap_norm(key, sq)
+        for p in psp_active:
+            g = g_psp[p].astype(F32)
+            u = unit_of(p)
+            sq[u] = sq[u] + jnp.sum(g * g, axis=tuple(range(1, g.ndim)))
+        if shard:
+            # the (B,) accumulators (and the clip factors derived from them)
+            # reduce locally at size B_local and STAY sharded into phase 3
+            sq = [_constrain(s, mesh, P(ba)) for s in sq]
+        unit_norms, unit_C = unit_clip_factors(res, sq)
+        if mask is not None:
+            unit_C = [c * mask for c in unit_C]
 
     # ---- phase 3: weighted gradients ----------------------------------------
-    def wgrad(key, ds):
-        path, kind, _ = parse_key(key)
-        wpath = path + "/w"
-        w = flat_params[wpath]
-        vocab = w.shape[-2] if kind == "emb" else 0
-        return record_weighted_grad(
-            key, acts_l[key], ds, unit_C[unit_of(wpath)], cache[key],
-            policy.use_kernels, w.dtype, vocab, mesh=mesh, shard=shard)
+    with jax.named_scope("bk_clipped_sum"):
+        def wgrad(key, ds):
+            path, kind, _ = parse_key(key)
+            wpath = path + "/w"
+            w = flat_params[wpath]
+            vocab = w.shape[-2] if kind == "emb" else 0
+            return record_weighted_grad(
+                key, acts_l[key], ds, unit_C[unit_of(wpath)], cache[key],
+                policy.use_kernels, w.dtype, vocab, mesh=mesh, shard=shard)
 
-    # streamed keys are absent from ``held``/``cache``: their grads landed
-    # in flat_grads during phase 2 and nothing of theirs survives to here
-    rec_keys = [k for k in active_taps
-                if k not in stream_keys and held[k] is None]
-    for key in active_taps:
-        if held.get(key) is not None:
-            ds_in = (held[key] if tape_pol[key] == "bf16"
-                     else load_record(held[key], tap_struct[key].dtype))
-            flat_grads[_tap_w(key)] = wgrad(key, ds_in)
-    if rec_keys:
-        # 'recompute' taps re-derive their weighted gradients with a
-        # REWEIGHTED-LOSS backward (the paper's module 2b'): for clip unit u,
-        # grad_w sum_i C_i^(u) L_i == sum_i C_i^(u) g_i[w] — one standard
-        # backward w.r.t. the chunk's ghost weights only, with the batch
-        # re-run through an UNTAPPED, non-collecting Tape. Nothing from
-        # phase 1 survives for these taps: their cotangents died at the
-        # norms, their activation records are never consumed in phase 3,
-        # and the re-derivation backward remats at the models' own
-        # jax.checkpoint scan-block boundaries. (A per-chunk tap-cotangent
-        # transpose was measured strictly worse: its zero tangents for
-        # every other tap materialize as full-size scan inputs.)
-        token = unit_C[0]
-        for u in range(len(res.units)):
-            rec_u = [k for k in rec_keys if unit_of(_tap_w(k)) == u]
-            if not rec_u:
+        # streamed keys are absent from ``held``/``cache``: their grads landed
+        # in flat_grads during phase 2 and nothing of theirs survives to here
+        rec_keys = [k for k in active_taps
+                    if k not in stream_keys and held[k] is None]
+        for key in active_taps:
+            if held.get(key) is None:
                 continue
-            nch = max(1, min(int(policy.tape_chunks), len(rec_u)))
-            size = -(-len(rec_u) // nch)
-            C_u = jax.lax.stop_gradient(unit_C[u])
-            for lo in range(0, len(rec_u), size):
-                group = rec_u[lo:lo + size]
-                wpaths = [_tap_w(k) for k in group]
+            with tap_scope(key):
+                ds_in = (held[key] if tape_pol[key] == "bf16"
+                         else load_record(held[key], tap_struct[key].dtype))
+                flat_grads[_tap_w(key)] = wgrad(key, ds_in)
+        if rec_keys:
+            # 'recompute' taps re-derive their weighted gradients with a
+            # REWEIGHTED-LOSS backward (the paper's module 2b'): for clip
+            # unit u, grad_w sum_i C_i^(u) L_i == sum_i C_i^(u) g_i[w] — one
+            # standard backward w.r.t. the chunk's ghost weights only, with
+            # the batch re-run through an UNTAPPED, non-collecting Tape.
+            # Nothing from phase 1 survives for these taps: their cotangents
+            # died at the norms, their activation records are never consumed
+            # in phase 3, and the re-derivation backward remats at the
+            # models' own jax.checkpoint scan-block boundaries. (A per-chunk
+            # tap-cotangent transpose was measured strictly worse: its zero
+            # tangents for every other tap materialize as full-size scan
+            # inputs.)
+            token = unit_C[0]
+            for u in range(len(res.units)):
+                rec_u = [k for k in rec_keys if unit_of(_tap_w(k)) == u]
+                if not rec_u:
+                    continue
+                nch = max(1, min(int(policy.tape_chunks), len(rec_u)))
+                size = -(-len(rec_u) // nch)
+                C_u = jax.lax.stop_gradient(unit_C[u])
+                for lo in range(0, len(rec_u), size):
+                    group = rec_u[lo:lo + size]
+                    wpaths = [_tap_w(k) for k in group]
 
-                def reweighted(wsub):
-                    merged = dict(flat_params)
-                    merged.update(psp0)
-                    merged.update(wsub)
-                    losses = apply_fn(unflatten(merged), batch,
-                                      Tape({}, collect=False))
-                    return jnp.sum(losses * C_u)
+                    def reweighted(wsub):
+                        merged = dict(flat_params)
+                        merged.update(psp0)
+                        merged.update(wsub)
+                        losses = apply_fn(unflatten(merged), batch,
+                                          Tape({}, collect=False))
+                        return jnp.sum(losses * C_u)
 
-                # the backward's cotangent seed goes through an optimization
-                # barrier CHAINED on the previous chunk's grads (the clip
-                # factors for the first): phase 2 completes — its cotangents
-                # freed — before any re-derivation runs, and the sweeps run
-                # one at a time so their live sets never overlap
-                seed, _ = jax.lax.optimization_barrier(
-                    (jnp.ones_like(loss_sum), token))
-                _, vjp_w = jax.vjp(reweighted,
-                                   {p: flat_params[p] for p in wpaths})
-                (gw,) = vjp_w(seed)
-                for p in wpaths:
-                    flat_grads[p] = gw[p].astype(flat_params[p].dtype)
-                token = flat_grads[wpaths[-1]]
-    for p in psp_active:
-        g = g_psp[p]
-        flat_grads[p] = jnp.einsum("b...,b->...", g.astype(F32),
-                                   unit_C[unit_of(p)]).astype(
-                                       flat_params[p].dtype)
-    for p in res.frozen:
-        flat_grads[p] = jnp.zeros_like(flat_params[p])
+                    # the backward's cotangent seed goes through an
+                    # optimization barrier CHAINED on the previous chunk's
+                    # grads (the clip factors for the first): phase 2
+                    # completes — its cotangents freed — before any
+                    # re-derivation runs, and the sweeps run one at a time so
+                    # their live sets never overlap
+                    seed, _ = jax.lax.optimization_barrier(
+                        (jnp.ones_like(loss_sum), token))
+                    _, vjp_w = jax.vjp(reweighted,
+                                       {p: flat_params[p] for p in wpaths})
+                    (gw,) = vjp_w(seed)
+                    for p in wpaths:
+                        flat_grads[p] = gw[p].astype(flat_params[p].dtype)
+                    token = flat_grads[wpaths[-1]]
+        for p in psp_active:
+            g = g_psp[p]
+            flat_grads[p] = jnp.einsum("b...,b->...", g.astype(F32),
+                                       unit_C[unit_of(p)]).astype(
+                                           flat_params[p].dtype)
+        for p in res.frozen:
+            flat_grads[p] = jnp.zeros_like(flat_params[p])
 
     if mask is not None:   # observability reports REAL samples only
         losses = losses[:B_real]
@@ -802,45 +827,52 @@ def monolithic_clipped_sum(apply_fn, params, batch, cfg, mesh=None):
         losses = apply_fn(unflatten(merged), batch, tape)
         return jnp.sum(losses), (losses, tape.acts)
 
-    loss_sum, vjp_fn, (losses, acts) = jax.vjp(run, taps0, psp0, has_aux=True)
-    ds_taps, g_psp = vjp_fn(jnp.ones_like(loss_sum))
+    with jax.named_scope("bk_taps"):
+        loss_sum, vjp_fn, (losses, acts) = jax.vjp(run, taps0, psp0,
+                                                   has_aux=True)
+        ds_taps, g_psp = vjp_fn(jnp.ones_like(loss_sum))
 
     unit_of = lambda p: res.unit_of[p]
-    sq = [jnp.zeros((B,), F32) for _ in res.units]
-    cache = {}
-    for key in active_taps:
-        wpath = _tap_w(key)
-        nk, cached = record_sq_norm(key, acts[key], ds_taps[key], policy.mode,
-                                    policy.use_kernels,
-                                    res.method_for(wpath), mesh=mesh,
-                                    shard=shard)
-        cache[key] = cached
-        u = unit_of(wpath)
-        sq[u] = sq[u] + nk
-    for p in psp_active:
-        g = g_psp[p].astype(F32)
-        u = unit_of(p)
-        sq[u] = sq[u] + jnp.sum(g * g, axis=tuple(range(1, g.ndim)))
-    if shard:
-        sq = [_constrain(s, mesh, P(ba)) for s in sq]
-    unit_norms, unit_C = unit_clip_factors(res, sq)
+    with jax.named_scope("bk_norms"):
+        sq = [jnp.zeros((B,), F32) for _ in res.units]
+        cache = {}
+        for key in active_taps:
+            wpath = _tap_w(key)
+            with tap_scope(key):
+                nk, cached = record_sq_norm(
+                    key, acts[key], ds_taps[key], policy.mode,
+                    policy.use_kernels, res.method_for(wpath), mesh=mesh,
+                    shard=shard)
+            cache[key] = cached
+            u = unit_of(wpath)
+            sq[u] = sq[u] + nk
+        for p in psp_active:
+            g = g_psp[p].astype(F32)
+            u = unit_of(p)
+            sq[u] = sq[u] + jnp.sum(g * g, axis=tuple(range(1, g.ndim)))
+        if shard:
+            sq = [_constrain(s, mesh, P(ba)) for s in sq]
+        unit_norms, unit_C = unit_clip_factors(res, sq)
 
     flat_grads = {}
-    for key in active_taps:
-        path, kind, _ = parse_key(key)
-        wpath = path + "/w"
-        w = flat_params[wpath]
-        vocab = w.shape[-2] if kind == "emb" else 0
-        flat_grads[wpath] = record_weighted_grad(
-            key, acts[key], ds_taps[key], unit_C[unit_of(wpath)], cache[key],
-            policy.use_kernels, w.dtype, vocab, mesh=mesh, shard=shard)
-    for p in psp_active:
-        g = g_psp[p]
-        flat_grads[p] = jnp.einsum("b...,b->...", g.astype(F32),
-                                   unit_C[unit_of(p)]).astype(
-                                       flat_params[p].dtype)
-    for p in res.frozen:
-        flat_grads[p] = jnp.zeros_like(flat_params[p])
+    with jax.named_scope("bk_clipped_sum"):
+        for key in active_taps:
+            path, kind, _ = parse_key(key)
+            wpath = path + "/w"
+            w = flat_params[wpath]
+            vocab = w.shape[-2] if kind == "emb" else 0
+            with tap_scope(key):
+                flat_grads[wpath] = record_weighted_grad(
+                    key, acts[key], ds_taps[key], unit_C[unit_of(wpath)],
+                    cache[key], policy.use_kernels, w.dtype, vocab,
+                    mesh=mesh, shard=shard)
+        for p in psp_active:
+            g = g_psp[p]
+            flat_grads[p] = jnp.einsum("b...,b->...", g.astype(F32),
+                                       unit_C[unit_of(p)]).astype(
+                                           flat_params[p].dtype)
+        for p in res.frozen:
+            flat_grads[p] = jnp.zeros_like(flat_params[p])
 
     return flat_grads, norm_aux(res, losses, sq, unit_norms, unit_C)
 

@@ -65,6 +65,7 @@ def clipped_grad(a, C, ds, block_d: int = 256, block_p: int = 256,
         out_specs=pl.BlockSpec((1, bd, bp), lambda l, i, j, b: (l, i, j)),
         out_shape=jax.ShapeDtypeStruct((L, D, P), F32),
         interpret=interpret,
+        name="clipped_grad",
     )(a, ds, C.astype(F32))
     out = out[:, :d, :p]
     return out[0] if squeeze else out

@@ -74,6 +74,7 @@ def emb_clipped_grad(ids, C, ds, vocab: int, block_v: int = 512,
         out_specs=pl.BlockSpec((1, bv, d), lambda l, v, b: (l, v, 0)),
         out_shape=jax.ShapeDtypeStruct((L, V, d), F32),
         interpret=interpret,
+        name="emb_clipped_grad",
     )(ids[..., None], ds, C.astype(F32))
     out = out[:, :vocab]
     return out[0] if squeeze else out

@@ -87,4 +87,5 @@ def emb_ghost_norm(ids, ds, block_t: int = 128, interpret: bool = False):
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="emb_ghost_norm",
     )(ij, ids_col, ids_row, ds, ds))

@@ -98,6 +98,7 @@ def fused_clip_grad(a, ds, w, clipping: str, R: float, gamma: float,
             sq_shape,
         ],
         interpret=interpret,
+        name="fused_clip_grad",
     )(a, ds, w.astype(F32))
     out = out[:, :d, :p]
     return (out[0] if squeeze else out), scalar_rows(sq)

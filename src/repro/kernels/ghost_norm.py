@@ -94,4 +94,5 @@ def ghost_norm(a, ds, block_t: int = 128, interpret: bool = False):
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="ghost_norm",
     )(ij, a, a, ds, ds))

@@ -67,5 +67,6 @@ def grad_norm_direct(a, ds, block_d: int = 256, block_p: int = 256,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="grad_norm_direct",
     )(a, ds)
     return scalar_rows(out)

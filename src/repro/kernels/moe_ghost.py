@@ -76,6 +76,7 @@ def moe_ghost_norm(a, mask, ds, interpret: bool = False):
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="moe_ghost_norm",
     )(a, mask, ds)
     return scalar_rows(out)
 
@@ -128,6 +129,7 @@ def moe_direct_norm(a, mask, ds, block_d: int = 256, block_p: int = 256,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="moe_direct_norm",
     )(a, mask, ds)
     return scalar_rows(out)
 
@@ -179,6 +181,7 @@ def moe_clipped_grad(a, mask, C, ds, block_d: int = 256, block_p: int = 256,
                                lambda l, e, i, j, b: (l, e, i, j)),
         out_shape=jax.ShapeDtypeStruct((L, E, D, P), F32),
         interpret=interpret,
+        name="moe_clipped_grad",
     )(a, mask, ds, C.astype(F32))
     out = out[:, :, :d, :p]
     return out[0] if squeeze else out

@@ -67,46 +67,50 @@ def init_train_state(params, opt_state, step: int, rng,
 
 def make_train_step(apply_fn, params_like, opt, opt_name: str, dp,
                     microbatch: int, mesh, batch_like):
-    """-> (step_fn, state_shardings, batch_shardings).
+    """-> (train_step, state_shardings, batch_shardings).
 
-    ``step_fn(state, batch) -> (new_state, loss)`` is pure and built for
+    ``train_step(state, batch) -> (new_state, loss)`` is pure and built for
 
-        jax.jit(step_fn, in_shardings=(state_sh, batch_sh),
+        jax.jit(train_step, in_shardings=(state_sh, batch_sh),
                 out_shardings=(state_sh, None), donate_argnums=(0,))
 
     Inside: BK runs mesh-lowered (batch-sharded book-keeping, one psum per
     weighted grad), phase-4 noise is generated shard-local, and — whenever
     the optimizer has a fused per-leaf path — the noise-add and the
     optimizer update happen in ONE pass over the leaves, so no second
-    full-parameter-size gradient tree is ever live."""
+    full-parameter-size gradient tree is ever live. That pass (or the
+    optimizer's update alone) runs in the ``jax.named_scope`` ``update``."""
     policy = as_policy(dp)
     state_sh = sh.named(mesh, sh.state_pspecs(opt_name, params_like, mesh))
     batch_sh = sh.named(mesh, sh.batch_pspecs(batch_like, mesh))
     flat_pspecs = sh.flat_param_pspecs(params_like, mesh)
     res = resolve_policy(policy, flatten(params_like))
 
-    def step_fn(state, batch):
+    def train_step(state, batch):
         rng = jax.random.fold_in(state.rng, state.step)
         if policy.mode in BK_MODES and opt.update_leaves is not None:
             sums, aux, B = accumulated_clipped_sum(
                 apply_fn, state.params, batch, policy, microbatch, mesh=mesh,
                 rng=rng)
-            leaf = noise_leaf_fn(policy, res, rng, float(B), step=state.step,
-                                 mesh=mesh, pspecs=flat_pspecs)
-            new_p, new_o = opt.update_leaves(
-                lambda path, p: leaf(path, sums[path]),
-                state.opt_state, state.params, state.step)
+            with jax.named_scope("update"):
+                leaf = noise_leaf_fn(policy, res, rng, float(B),
+                                     step=state.step, mesh=mesh,
+                                     pspecs=flat_pspecs)
+                new_p, new_o = opt.update_leaves(
+                    lambda path, p: leaf(path, sums[path]),
+                    state.opt_state, state.params, state.step)
         else:
             grads, aux = accumulated_private_grad(
                 apply_fn, state.params, batch, rng, policy, microbatch,
                 state.step, mesh=mesh, pspecs=flat_pspecs)
-            new_p, new_o = opt.update(grads, state.opt_state, state.params,
-                                      state.step)
+            with jax.named_scope("update"):
+                new_p, new_o = opt.update(grads, state.opt_state,
+                                          state.params, state.step)
         new_state = TrainState(params=new_p, opt_state=new_o,
                                step=state.step + 1, rng=state.rng)
         return new_state, aux["loss"]
 
-    return step_fn, state_sh, batch_sh
+    return train_step, state_sh, batch_sh
 
 # physical (micro) batch for train_4k, tuned so the per-device book-keeping
 # footprint stays within v5e HBM (see EXPERIMENTS.md §Dry-run)

@@ -5,12 +5,14 @@ Mosaic refuses, so each main-path kernel is compiled here, at qwen2-1.5b
 widths (d 1536, qkv 2048, MLP up 17920 / down 8960, vocab 151936; batch 4 x
 seq 512, 4 stacked layers), with the blocks `kernels.dispatch` plans for
 those shapes, for a v5e chip that is described, not attached. Nothing runs;
-a lowering, VMEM or SMEM refusal fails the test.
+a lowering, VMEM or SMEM refusal fails the test. Each kernel's custom call
+must carry the kernel's name, which the profiler shows.
 
 The topology is described inside a module fixture: only the worker that
 runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +58,17 @@ def _compile(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _kernel(one_chip, fn, *shapes):
+def _kernel(one_chip, name, fn, *shapes):
+    """Compile ``fn``, which runs the Pallas kernel ``name``: its custom
+    call is named after the kernel, and so is the call's scope in the op
+    path (``pallas_call(name=...)``)."""
     compiled = _compile(one_chip, fn, *shapes)
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        assert re.match(rf"\s*(ROOT )?%{name}(\.\d+)? = ", line), line[:80]
+        assert re.search(rf'op_name="[^"]*/{name}/pallas_call"', line)
     return compiled
 
 
@@ -71,21 +81,23 @@ IDS, EMB_DS = (B, T), (B, T, D)
 def test_ghost_norm_mlp_up(one_chip):
     plan = dispatch.norm_plan("mm", UP_A, UP_DS, MODE)
     assert (plan.impl, plan.method) == ("kernel", "ghost"), plan
-    _kernel(one_chip, lambda a, g: ghost_norm(a, g, **plan.kwargs()),
+    _kernel(one_chip, "ghost_norm",
+            lambda a, g: ghost_norm(a, g, **plan.kwargs()),
             (UP_A, BF16), (UP_DS, BF16))
 
 
 def test_grad_norm_direct_mlp_up(one_chip):
     plan = dispatch.norm_plan("mm", UP_A, UP_DS, MODE, method="direct")
     assert (plan.impl, plan.method) == ("kernel", "direct"), plan
-    _kernel(one_chip, lambda a, g: grad_norm_direct(a, g, **plan.kwargs()),
+    _kernel(one_chip, "grad_norm_direct",
+            lambda a, g: grad_norm_direct(a, g, **plan.kwargs()),
             (UP_A, BF16), (UP_DS, BF16))
 
 
 def test_clipped_grad_lm_head(one_chip):
     plan = dispatch.grad_plan("mm", HEAD_A, HEAD_DS)
     assert plan.impl == "kernel", plan
-    _kernel(one_chip,
+    _kernel(one_chip, "clipped_grad",
             lambda a, c, g: clipped_grad(a, c, g, **plan.kwargs()),
             (HEAD_A, BF16), ((B,), F32), (HEAD_DS, BF16))
 
@@ -98,7 +110,7 @@ def test_fused_clip_layer_scope_adapter(one_chip):
     plan = dispatch.fused_plan("mm", a, ds, MODE)
     assert (plan.impl, plan.method) == ("kernel", "fused"), plan
     assert dispatch.fused_plan("mm", UP_A, UP_DS, MODE).method == "split"
-    _kernel(one_chip,
+    _kernel(one_chip, "fused_clip_grad",
             lambda x, g, w: fused_clip_grad(x, g, w, clipping="automatic",
                                             R=1.0, gamma=0.01),
             (a, BF16), (ds, BF16), ((B,), F32))
@@ -107,14 +119,15 @@ def test_fused_clip_layer_scope_adapter(one_chip):
 def test_emb_ghost_norm(one_chip):
     plan = dispatch.norm_plan("emb", IDS, EMB_DS, MODE)
     assert plan.impl == "kernel", plan
-    _kernel(one_chip, lambda i, g: emb_ghost_norm(i, g, **plan.kwargs()),
+    _kernel(one_chip, "emb_ghost_norm",
+            lambda i, g: emb_ghost_norm(i, g, **plan.kwargs()),
             (IDS, I32), (EMB_DS, BF16))
 
 
 def test_emb_clipped_grad(one_chip):
     plan = dispatch.grad_plan("emb", IDS, EMB_DS, vocab=V)
     assert plan.impl == "kernel", plan
-    _kernel(one_chip,
+    _kernel(one_chip, "emb_clipped_grad",
             lambda i, c, g: emb_clipped_grad(i, c, g, vocab=V,
                                              **plan.kwargs()),
             (IDS, I32), ((B,), F32), (EMB_DS, BF16))
