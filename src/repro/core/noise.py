@@ -114,7 +114,14 @@ def counter_normal(rng, shape, dtype=jnp.float32, offsets=None,
     Tensors past 2^32 elements split the counter across BOTH threefry
     words: the trailing dims that fit a uint32 ride word 0 (so tensors
     under 2^32 keep their exact pre-split draws), the leading-block index
-    rides word 1."""
+    rides word 1.
+
+    The counter planes, the threefry block and the normal all keep the
+    leaf's own ``shape``. On a TPU a rank-2 array is tiled (8, 128) and a
+    rank-1 one is not, so flattening would be a physical relayout, which
+    keeps XLA from fusing the draw into the leaf's optimizer update and
+    costs separate passes over HBM. Elementwise on the leaf's shape, the
+    whole draw fuses into that update."""
     from jax.extend.random import threefry2x32_p
     full = tuple(full_shape) if full_shape is not None else tuple(shape)
     # split point: dims [k:] index counter word 0 exactly; dims [:k] word 1
@@ -139,14 +146,14 @@ def counter_normal(rng, shape, dtype=jnp.float32, offsets=None,
                 coord = coord + jnp.uint32(offsets[d])
             idx = idx + coord * jnp.uint32(stride)
             stride *= int(full[d])
-        return idx.reshape(-1)
+        return idx
 
     key = _raw_key(rng)
     lo, hi = plane(range(k, len(full))), plane(range(k))
     bits, _ = threefry2x32_p.bind(jnp.broadcast_to(key[0], lo.shape),
                                   jnp.broadcast_to(key[1], lo.shape),
                                   lo, hi)
-    return normal_from_bits(bits.reshape(shape), dtype)
+    return normal_from_bits(bits, dtype)
 
 
 def normal_from_bits(bits, dtype=jnp.float32):

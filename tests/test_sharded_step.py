@@ -231,6 +231,70 @@ def test_counter_normal_wide_counter_consistency():
         counter_normal(rng, (4,), offsets=(0,), full_shape=(1 << 33,))
 
 
+def _jaxpr_eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _jaxpr_eqns(inner)
+
+
+def _flat_counter_normal(rng, shape, offsets=None, full_shape=None):
+    """The noise by its definition, on a flat counter: element i (row-major
+    within ``full_shape``) is the inverse normal CDF of the top 24 bits of
+    the threefry-2x32 block of (key, (i, 0)), at the centre of its cell,
+    the top cell held below 1. Fewer than 2^32 elements."""
+    import jax.numpy as jnp
+    from jax.extend.random import threefry2x32_p
+    from jax.scipy.special import ndtri
+
+    full = full_shape or shape
+    offs = offsets or (0,) * len(shape)
+    coords = tuple(c + o for c, o in zip(np.indices(shape), offs))
+    lo = jnp.asarray(np.ravel_multi_index(coords, full).reshape(-1),
+                     jnp.uint32)
+    bits, _ = threefry2x32_p.bind(jnp.broadcast_to(rng[0], lo.shape),
+                                  jnp.broadcast_to(rng[1], lo.shape),
+                                  lo, jnp.zeros_like(lo))
+    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24) \
+        + jnp.float32(2 ** -25)
+    u = jnp.minimum(u, jnp.float32(1 - 2 ** -24))
+    return np.asarray(ndtri(u)).reshape(shape)
+
+
+@pytest.mark.parametrize("shape", [(48, 40), (3, 20, 36)])
+def test_counter_normal_draws_on_the_leafs_shape(shape):
+    """The draw is built on the leaf's shape: no reshape anywhere in its
+    jaxpr, and the threefry block runs on leaf-shaped operands (on a TPU a
+    flattened draw is a relayout that XLA does not fuse into the leaf's
+    update). Its values are the flat definition's, element by element,
+    with and without shard offsets."""
+    import jax
+
+    from repro.core.noise import counter_normal
+
+    rng = jax.random.PRNGKey(11)
+    jaxpr = jax.make_jaxpr(lambda r: counter_normal(r, shape))(rng).jaxpr
+    eqns = list(_jaxpr_eqns(jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "reshape"]
+    fry = [e for e in eqns if e.primitive.name == "threefry2x32"]
+    assert len(fry) == 1
+    assert [tuple(v.aval.shape) for v in fry[0].invars] == [shape] * 4
+    assert [tuple(v.aval.shape) for v in fry[0].outvars] == [shape] * 2
+
+    np.testing.assert_array_equal(np.asarray(counter_normal(rng, shape)),
+                                  _flat_counter_normal(rng, shape))
+    # a device's block of a larger tensor, at its global offsets
+    full = tuple(2 * s + 1 for s in shape)
+    offs = tuple(range(1, len(shape) + 1))
+    np.testing.assert_array_equal(
+        np.asarray(counter_normal(rng, shape, offsets=offs, full_shape=full)),
+        _flat_counter_normal(rng, shape, offs, full))
+
+
 PADDED = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np

@@ -6,7 +6,8 @@ widths (d 1536, qkv 2048, MLP up 17920 / down 8960, vocab 151936; batch 4 x
 seq 512, 4 stacked layers), with the blocks `kernels.dispatch` plans for
 those shapes, for a v5e chip that is described, not attached. Nothing runs;
 a lowering, VMEM or SMEM refusal fails the test. Each kernel's custom call
-must carry the kernel's name, which the profiler shows.
+must carry the kernel's name, which the profiler shows. A small whole BK
+step is compiled too, to check how the DP noise is laid out in it.
 
 The topology is described inside a module fixture: only the worker that
 runs this file loads the TPU compiler.
@@ -16,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -140,3 +142,65 @@ def test_lm_head_ghost_norm_plan(one_chip):
     assert (plan.impl, plan.method) == ("jnp", "ghost"), plan
     _compile(one_chip, ghost.sq_norm_mm_ghost,
              (HEAD_A, BF16), (HEAD_DS, BF16))
+
+
+# the small BK step of bench/tests/record_step_trace.py: qwen2.5-3b's layout
+# at d 256, 2 layers, vocab 32768; batch 4 x seq 512; sigma 1, AdamW
+SMALL = dict(n_layers=2, d_model=256, d_ff=512, n_heads=2, n_kv_heads=1,
+             head_dim=128, vocab=32768)
+
+
+def test_noise_draw_keeps_each_leafs_shape(one_chip, monkeypatch):
+    """The DP noise of every leaf is drawn on the leaf's own shape. A draw
+    flattened to rank 1 is a relayout on a TPU (tiled (8, 128) vs linear)
+    that XLA does not fuse into the leaf's AdamW update: the compiled step
+    would hold a leaf-sized rank-1 counter or normal array between
+    separate passes over HBM."""
+    from repro.configs.registry import build, get_config
+    from repro.core.bk import DPConfig
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh
+    from repro.launch.steps import TrainState, make_train_step
+    from repro.optim.optimizers import make_optimizer
+    from repro.utils.tree import flatten
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = build(get_config("qwen2.5-3b").with_(**SMALL))
+    mesh = make_mesh((1, 1), ("data", "model"),
+                     devices=[next(iter(one_chip.device_set))])
+    opt = make_optimizer("adamw", lambda s: jnp.asarray(3e-4, F32))
+    params = jax.eval_shape(model.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 512), I32)}
+    dp = DPConfig(mode=MODE, clipping="automatic", R=1.0, gamma=0.01,
+                  sigma=1.0)
+    step, state_sh, batch_sh = make_train_step(
+        model.apply, params, opt, "adamw", dp, 0, mesh, batch)
+    state = TrainState(params=params, opt_state=jax.eval_shape(opt.init,
+                                                               params),
+                       step=jax.ShapeDtypeStruct((), I32),
+                       rng=jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            tree, shardings)
+
+    state, batch = placed(state, state_sh), placed(batch, batch_sh)
+    with mesh:
+        text = jax.jit(step, in_shardings=(state_sh, batch_sh),
+                       out_shardings=(state_sh, None),
+                       donate_argnums=(0,)).lower(state, batch).compile() \
+            .as_text()
+
+    # element counts of the leaves of rank 2 and up, less the sizes of
+    # single dims: a rank-1 array of a dim's size is a broadcast factor
+    leaves = flatten(params).values()
+    sizes = ({int(np.prod(p.shape)) for p in leaves if p.ndim > 1}
+             - {d for p in leaves for d in p.shape})
+    assert 32768 * 256 in sizes
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    flat = [line.strip()[:120] for line in entry.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%\S+ = \w+\[(\d+)\]", line))
+            and int(m.group(1)) in sizes]
+    assert not flat, flat
