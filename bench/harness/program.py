@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from harness import seeds
-from reference.qwen2 import param_shapes
+from reference import reference_for
 
 
 class ProgramMissing(RuntimeError):
@@ -54,20 +54,11 @@ def unflatten(flat: dict) -> dict:
 def model_config(cfg: dict):
     """The program's ModelConfig for a benchmark configuration file: the
     arch's registered config at the sizes the file states, checked against
-    what else the file states."""
+    what else the file states; the file's reference maps the one onto the
+    other."""
     from repro.configs.registry import get_config
-    mc = get_config(cfg["arch"]).with_(
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        d_ff=cfg["intermediate_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        vocab=cfg["vocab_size"])
-    # the program never ties embedding and LM head: a departure the file
-    # states under "assumed", mirrored by the reference
-    stated = {
-        "rope_theta": cfg["rope_theta"], "qkv_bias": cfg["attention_bias"],
-        "dtype": cfg["torch_dtype"], "param_dtype": cfg["torch_dtype"],
-        "act": "swiglu", "norm": "rmsnorm", "family": "dense",
-    }
+    sizes, stated = reference_for(cfg).program_config(cfg)
+    mc = get_config(cfg["arch"]).with_(**sizes)
     wrong = {k: (getattr(mc, k), v) for k, v in stated.items()
              if getattr(mc, k) != v}
     if wrong:
@@ -126,7 +117,7 @@ class TrainStep:
         p_struct = jax.eval_shape(self.model.init,
                                   jax.ShapeDtypeStruct((2,), jnp.uint32))
         shapes = {p: tuple(s.shape) for p, s in flatten(p_struct).items()}
-        if shapes != param_shapes(cfg):
+        if shapes != reference_for(cfg).param_shapes(cfg):
             raise ValueError("the program's parameter layout differs from "
                              "the reference's")
         b_struct = {"tokens": jax.ShapeDtypeStruct((self.B, self.T),

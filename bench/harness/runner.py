@@ -102,30 +102,19 @@ def _update_norms(params, seed: int, shapes: dict):
     return {p: float(v) for p, v in jax.jit(norms)(flat, p0).items()}
 
 
-def model_flops_per_token(cfg: dict, T: int) -> float:
-    """PaLM (arXiv:2204.02311, app. B): 6 N + 12 L H Q T, N the parameters
-    in matrix products (LM head in, embedding gather out), Q the head size,
-    T the sequence. Recomputation and DP's own norm work are not counted."""
-    from reference.qwen2 import param_shapes
-    n = sum(math.prod(s) for p, s in param_shapes(cfg).items()
-            if p.endswith("/w") and p != "embed/w")
-    return 6.0 * n + 12.0 * (cfg["num_hidden_layers"] * T
-                             * cfg["num_attention_heads"] * cfg["head_dim"])
-
-
 def checked_steps(step, feed, cfg: dict, tr: dict, seed: int) -> dict:
     """Drive ``step`` through its first ``check_steps`` steps with the
     window's own call and feed; -> what the comparison reads: each step's
     loss, the first averaged gradient (norms, and the bf16 leaves on the
     host), the parameters' change after those steps."""
-    from reference.qwen2 import param_shapes
+    from reference import reference_for
     prog = {"losses": []}
     for t in range(tr["check_steps"]):
         prog["losses"].append(float(step(feed(t))))
         if t == 0:
             prog["g0_norm"], prog["g0"] = _first_grad(step.state, tr["b1"])
     prog["upd_norm"] = _update_norms(step.state.params, seed,
-                                     param_shapes(cfg))
+                                     reference_for(cfg).param_shapes(cfg))
     return prog
 
 
@@ -145,6 +134,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
 
     from harness import check, feed as feed_mod
     from harness.program import TrainStep, import_program
+    from reference import reference_for
     from reference.dp_step import DPReference
 
     require_chips(cell)
@@ -246,7 +236,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                                peaks.peak(device["kind"]), costs)
         ctx = types.SimpleNamespace(      # what a metric's reader may read
             cell=cell, chips=cell.chips, tokens_per_s=tokens_per_s,
-            flops_per_token=model_flops_per_token(cfg, T),
+            flops_per_token=reference_for(cfg).flops_per_token(cfg, T),
             peak=peaks.peak(device["kind"]), trace=red,
             temp_bytes=temp_bytes)
         device["busy_s"] = red["busy_s"]

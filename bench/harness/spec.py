@@ -1,6 +1,8 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 configs/<config>.json       a configuration, its sizes as run
+reference/<reference>.py    the plain model the configuration's
+                            ``reference`` key names (reference/__init__.py)
 traffic/<traffic>.json      a traffic mix: the parameters the feed and the
                             step are built from
 limits/<cell>.json          the limits of the numbers that decide
@@ -13,6 +15,8 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+
+from reference import reference_for
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
@@ -46,6 +50,7 @@ class Cell:
             self.workload["config"]]
         self.config_name = conf["name"]
         self.config = load_json(os.path.join(root, conf["file"]))
+        reference_for(self.config, conf["file"])     # fails early, by name
         self.traffic_name = self.workload["traffic"]
         self.traffic = load_json(self.path("traffic", self.traffic_name
                                            + ".json"))

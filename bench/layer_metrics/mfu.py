@@ -1,5 +1,7 @@
-"""mfu (%): model FLOPs per token (PaLM app. B: 6 N + 12 L H Q T) times the
-traced run's tokens/s, over chips x the bf16 peak of the device kind."""
+"""mfu (%): model FLOPs per token, as the cell's reference counts them
+(``flops_per_token`` of the module its configuration's ``reference`` key
+names; qwen2's is PaLM app. B's 6 N + 12 L H Q T), times the traced run's
+tokens/s, over chips x the bf16 peak of the device kind."""
 
 
 def read(ctx):

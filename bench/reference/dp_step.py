@@ -1,4 +1,5 @@
-"""Plain float32 reference of the first training steps of a cell.
+"""Plain float32 reference of the first training steps of a cell, on the
+model of the reference module that the configuration names.
 
 Per step, one row at a time: the row's own gradient (``jax.grad`` of its
 loss) -> per clipping unit, that row's norm and automatic clip factor
@@ -32,8 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from harness import seeds
-from reference import noise
-from reference.qwen2 import param_shapes, row_loss
+from reference import noise, reference_for
 
 F32 = jnp.float32
 
@@ -90,14 +90,15 @@ class DPReference:
     def __init__(self, cfg: dict, traffic: dict, precision: str = "float32",
                  device=None):
         self.cfg, self.tr = cfg, traffic
-        self.shapes = param_shapes(cfg)
+        model = reference_for(cfg)
+        self.shapes = model.param_shapes(cfg)
         self.paths = sorted(self.shapes)
         self.private = traffic["mode"] != "nonprivate"
         self.unit_of, self.units = clip_units(cfg["dp_groups"], self.paths)
         self.sens = math.sqrt(sum(u["R"] ** 2 for u in self.units))
         self.lo = LOWER[precision]
         self.device = device or jax.devices()[0]
-        loss = functools.partial(row_loss, cfg=cfg, lo=self.lo)
+        loss = functools.partial(model.row_loss, cfg=cfg, lo=self.lo)
         uidx = {p: self.unit_of[p] for p in self.paths}
         nu = len(self.units)
 

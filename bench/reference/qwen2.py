@@ -6,6 +6,9 @@ plain f32 product in bf16 passes otherwise). ``lo`` rounds every matrix
 operand; it is the identity for the reference and a float8 round trip for
 the lower-precision control.
 
+This is the ``reference`` that the qwen2 and qwen2.5 configuration files
+name (interface: ``reference/__init__.py``).
+
 The parameter layout follows the published architecture in the stacked form
 the program trains: fused q|k|v projection with bias, o without, SwiGLU with
 gate|up fused (gate first), RMSNorm gains, separate embedding and LM head.
@@ -14,11 +17,18 @@ head; here (as in the program) the two are separate leaves.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
+
+# the sizes at which the CPU tests run a qwen2 configuration
+TINY = dict(hidden_size=32, intermediate_size=48, num_attention_heads=4,
+            num_key_value_heads=2, head_dim=8, num_hidden_layers=2,
+            vocab_size=64)
 
 
 def sizes(cfg: dict) -> dict:
@@ -46,6 +56,35 @@ def param_shapes(cfg: dict) -> dict:
         "final_norm/g": (d,),
         "head/w": (d, V),
     }
+
+
+def flops_per_token(cfg: dict, T: int) -> float:
+    """PaLM (arXiv:2204.02311, app. B): 6 N + 12 L H Q T, N the parameters
+    in matrix products (LM head in, embedding gather out), Q the head size,
+    T the sequence. Recomputation and DP's own norm work are not counted."""
+    n = sum(math.prod(s) for p, s in param_shapes(cfg).items()
+            if p.endswith("/w") and p != "embed/w")
+    return 6.0 * n + 12.0 * (cfg["num_hidden_layers"] * T
+                             * cfg["num_attention_heads"] * cfg["head_dim"])
+
+
+def program_config(cfg: dict) -> tuple:
+    """-> (sizes, stated): the program's ModelConfig fields at the sizes
+    the file states, and the fields the arch's registered config must
+    already have."""
+    sizes = dict(
+        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        d_ff=cfg["intermediate_size"], n_heads=cfg["num_attention_heads"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        vocab=cfg["vocab_size"])
+    # the program never ties embedding and LM head: a departure the file
+    # states under "assumed", mirrored by this reference
+    stated = {
+        "rope_theta": cfg["rope_theta"], "qkv_bias": cfg["attention_bias"],
+        "dtype": cfg["torch_dtype"], "param_dtype": cfg["torch_dtype"],
+        "act": "swiglu", "norm": "rmsnorm", "family": "dense",
+    }
+    return sizes, stated
 
 
 def _rmsnorm(x, g, eps):

@@ -12,26 +12,28 @@ ROOT = os.path.dirname(BENCH)
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-TINY = dict(hidden_size=32, intermediate_size=48, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=8, num_hidden_layers=2,
-            vocab_size=64)
 # limits for the tiny cell: sound runs read loss ~2e-5, grad ~1e-3,
 # update ~3e-3, signal ~0.07 here (bf16 program against the f32 reference)
 TINY_LIMITS = {"loss": 3e-4, "grad": 2e-2, "update": 5e-2, "signal": 0.15}
 
 
 class TinyCell:
-    """Duck-types harness.spec.Cell for harness.runner.run_cell."""
+    """Duck-types harness.spec.Cell for harness.runner.run_cell: the
+    configuration at its reference's ``TINY`` sizes; ``reference`` names
+    another reference module than the file's."""
 
     def __init__(self, config="qwen2-1.5b", traffic="s512.bk", chips=1,
-                 seq=16, batch_per_chip=4):
+                 seq=16, batch_per_chip=4, reference=None):
         from harness.spec import load_json
+        from reference import reference_for
         self.root = ROOT
         self.name = f"tiny.{config}.{traffic}"
         self.chips = chips
         self.config = load_json(os.path.join(BENCH, "configs",
                                              config + ".json"))
-        self.config.update(TINY)
+        if reference is not None:
+            self.config["reference"] = reference
+        self.config.update(reference_for(self.config).TINY)
         self.traffic = load_json(os.path.join(BENCH, "traffic",
                                               traffic + ".json"))
         self.traffic.update(seq=seq, batch_per_chip=batch_per_chip,

@@ -77,48 +77,84 @@ def test_cells_configs_and_chips():
         assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
 
 
+def _config(name: str) -> dict:
+    conf = next(c for c in SPEC["configs"] if c["name"] == name)
+    with open(os.path.join(ROOT, conf["file"])) as f:
+        return json.load(f)
+
+
+def _chips(name: str) -> int:
+    return min(w["chips"] for w in SPEC["workloads"] if w["config"] == name)
+
+
+def _cut_allowed(key: str) -> bool:
+    """Guide section 4's cuts: the depth, a slice of the vocabulary, the
+    experts or heads this chip holds of a layer. Never a width."""
+    return key in ("num_hidden_layers", "vocab_size") or (
+        key.startswith(("num_", "n_"))
+        and key.endswith(("experts", "_heads")))
+
+
 @pytest.mark.parametrize("name", CONFIGS)
 def test_config_file_is_under_paths_and_lists_its_cuts(name):
     conf = next(c for c in SPEC["configs"] if c["name"] == name)
     assert conf["file"].startswith("bench/configs/")
-    with open(os.path.join(ROOT, conf["file"])) as f:
-        cfg = json.load(f)
+    cfg = _config(name)
     assert cfg["reduced"] == conf["reduced"]
     assert set(cfg["published"]) == set(cfg["reduced"])
     for k in cfg["reduced"]:
-        assert not k.endswith(("_dim", "_rank", "_size")), k
+        assert _cut_allowed(k), k
+        assert k == "vocab_size" or not k.endswith(
+            ("_dim", "_rank", "_size")), k
+        assert cfg[k] < cfg["published"][k], k
+        if k == "vocab_size":
+            assert 8 * cfg[k] >= cfg["published"][k]
+        elif k.endswith("experts"):
+            assert cfg[k] >= 8, k
+    if set(cfg["reduced"]) - {"num_hidden_layers"}:
+        assert cfg.get("deployment")
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_config_runs_the_registered_widths(name):
-    """The cells run the arch's published widths: only the depth is cut."""
+    """The registered config is the published model: at the published
+    sizes the reference's program_config gives it field by field, and the
+    file departs from it only in what it lists as reduced, downwards."""
     from harness.program import import_program, model_config
-    from harness.spec import load_json
+    from reference import reference_for
     import_program(ROOT)
     from repro.configs.registry import get_config
-    conf = next(c for c in SPEC["configs"] if c["name"] == name)
-    cfg = load_json(os.path.join(ROOT, conf["file"]))
+    cfg = _config(name)
+    ref = reference_for(cfg)
     mc, reg = model_config(cfg), get_config(cfg["arch"])
-    for k in ("d_model", "d_ff", "n_heads", "n_kv_heads", "hd", "vocab"):
-        assert getattr(mc, k) == getattr(reg, k), k
-    assert mc.n_layers == cfg["num_hidden_layers"] < reg.n_layers
+    sizes, stated = ref.program_config(cfg)
+    published, _ = ref.program_config({**cfg, **cfg["published"]})
+    for k, v in stated.items():
+        assert getattr(reg, k) == v, k
+    for k, v in published.items():
+        assert getattr(reg, k) == v, k
+        assert getattr(mc, k) == sizes[k], k
+    cut = {k for k in sizes if sizes[k] != published[k]}
+    assert len(cut) == len(cfg["reduced"])
+    assert all(sizes[k] < published[k] for k in cut)
 
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_layout_matches_program(name):
+    """The program's layout is the reference's, and its training state (bf16
+    weights, f32 AdamW moments: 10 bytes a parameter) fits the chips of
+    the configuration's cells."""
     import jax
     import jax.numpy as jnp
     from harness.program import flatten, import_program, model_config
-    from harness.spec import load_json
-    from reference.qwen2 import param_shapes
+    from reference import reference_for
     import_program(ROOT)
     from repro.configs.registry import build
-    conf = next(c for c in SPEC["configs"] if c["name"] == name)
-    cfg = load_json(os.path.join(ROOT, conf["file"]))
+    cfg = _config(name)
     model = build(model_config(cfg))
     shapes = jax.eval_shape(model.init, jax.ShapeDtypeStruct((2,),
                                                              jnp.uint32))
     got = {p: tuple(s.shape) for p, s in flatten(shapes).items()}
-    assert got == param_shapes(cfg)
+    assert got == reference_for(cfg).param_shapes(cfg)
     n = sum(math.prod(s) for s in got.values())
-    assert 0.5e9 < n < 1.0e9
+    assert 10 * n < 16 * 2 ** 30 * _chips(name)
