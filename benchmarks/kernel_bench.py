@@ -64,7 +64,7 @@ def _cdiv(a, b):
     return -(-a // b)
 
 
-def _dma_models(L, B, T, d, p, V, E, C, bt, bte, bd, bp, bv, mbd, mbp):
+def _dma_models(L, B, T, d, p, V, bt, bte, bd, bp, bv):
     """Per-cell TPU DMA traffic: grid steps x fetched block bytes + output
     bytes (each output tile is accumulated in VMEM and written once)."""
     f = 4  # f32 operand bytes (int32 ids likewise)
@@ -73,7 +73,6 @@ def _dma_models(L, B, T, d, p, V, E, C, bt, bte, bd, bp, bv, mbd, mbp):
     nte = _cdiv(T, bte)
     trie = nte * (nte + 1) // 2
     nd, np_ = _cdiv(d, bd), _cdiv(p, bp)
-    mnd, mnp = _cdiv(d, mbd), _cdiv(p, mbp)
     nv = _cdiv(V, bv)
     return {
         "ghost_norm_mm": B * L * tri * 2 * bt * (d + p) * f + B * f,
@@ -83,36 +82,24 @@ def _dma_models(L, B, T, d, p, V, E, C, bt, bte, bd, bp, bv, mbd, mbp):
         "ghost_norm_emb": B * L * trie * 2 * bt * (1 + d) * f + B * f,
         "clipped_grad_emb": (L * nv * B * (T * (1 + d) + 1) * f
                              + L * V * d * f),
-        "ghost_norm_moe": B * L * E * C * (d + p + 1) * f + B * f,
-        "direct_norm_moe": (B * L * E * mnd * mnp * C * (mbd + mbp + 1) * f
-                            + B * f),
-        "clipped_grad_moe": (L * E * mnd * mnp * B * (C * (mbd + mbp + 1) + 1)
-                             * f + L * E * d * p * f),
     }
 
 
 def _cells(fast: bool):
     L, B, T, d, p = (2, 4, 128, 32, 32) if fast else (4, 8, 256, 64, 64)
     V = 256 if fast else 1024
-    E, C = (4, 16) if fast else (8, 32)
 
     a, ds = _mk((L, B, T, d)), _mk((L, B, T, p), 1)
     Cw = jnp.abs(_mk((B,), 2)) + 0.1
     ids = jax.random.randint(jax.random.PRNGKey(3), (L, B, T), 0, V)
     de = _mk((L, B, T, d), 4)
-    ma = _mk((L, B, E, C, d), 5)
-    mm = (jax.random.uniform(jax.random.PRNGKey(6), (L, B, E, C)) > 0.3
-          ).astype(F32)
-    mds = _mk((L, B, E, C, p), 7)
-    rec = {"a": ma, "mask": mm}
 
     # block sizes from the same analytic model dispatch uses for its plans
     bt = dispatch.block_t_ghost(T, d, p)
     bte = dispatch.block_t_ghost(T, d, d, lane=True)
     bd, bp = dispatch.block_dp(T, d, p)
     bv = dispatch.block_v(T, d, V)
-    mbd, mbp = dispatch.block_dp(C, d, p)
-    dma = _dma_models(L, B, T, d, p, V, E, C, bt, bte, bd, bp, bv, mbd, mbp)
+    dma = _dma_models(L, B, T, d, p, V, bt, bte, bd, bp, bv)
     # cell -> (kernel_fn, ref_fn, args): args flow through jit parameters
     return dma, {
         "ghost_norm_mm": (
@@ -133,17 +120,6 @@ def _cells(fast: bool):
             lambda i, c, g: ops.clipped_grad_emb(i, c, g, V, block_v=bv),
             lambda i, c, g: ghost.weighted_grad_emb(i, c, g, V, F32),
             (ids, Cw, de)),
-        "ghost_norm_moe": (
-            lambda r, g: ops.ghost_norm_moe(r, g),
-            lambda r, g: ghost.sq_norm_moe_ghost(r, g), (rec, mds)),
-        "direct_norm_moe": (
-            lambda r, g: ops.direct_norm_moe(r, g, block_d=mbd, block_p=mbp),
-            lambda r, g: ghost.sq_norm_moe_direct(r, g), (rec, mds)),
-        "clipped_grad_moe": (
-            lambda r, c, g: ops.clipped_grad_moe(r, c, g, block_d=mbd,
-                                                 block_p=mbp),
-            lambda r, c, g: ghost.weighted_grad_moe(r, c, g, F32),
-            (rec, Cw, mds)),
     }
 
 

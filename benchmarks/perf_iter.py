@@ -50,8 +50,6 @@ VARIANTS = {
                            "microbatch": 128},
     "seq-shard+nonprivate": {"cfg_patch": {"seq_shard_attn": True},
                              "dp": DPConfig(mode="nonprivate")},
-    "cap-1.0": {"cfg_patch": {"capacity_factor": 1.0}},
-    "cap-2.0": {"cfg_patch": {"capacity_factor": 2.0}},
     "adamw": {"optimizer": "adamw"},
     "adafactor": {"optimizer": "adafactor"},
     "ssm-chunk-64": {"cfg_patch": {"ssm_chunk": 64}},

@@ -28,12 +28,13 @@ class ModelConfig:
     remat: bool = False
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0           # routed experts: the router scores them all
     top_k: int = 0
+    experts_held: int = 0        # routed experts this chip holds, from
+    first_expert: int = 0        # first_expert on (0: all of them)
     n_shared: int = 0
     moe_d_ff: int = 0
     first_k_dense: int = 0       # leading dense-FFN layers (DeepSeekMoE style)
-    capacity_factor: float = 2.0
     renorm_topk: bool = True
 
     # SSM / hybrid

@@ -88,7 +88,7 @@ def smoke_config(name: str) -> ModelConfig:
     kw = dict(n_layers=2, d_model=32, n_heads=4, n_kv_heads=2, head_dim=8,
               d_ff=48, vocab=64, max_t=64)
     if cfg.family == "moe":
-        kw.update(n_experts=4, top_k=2, moe_d_ff=16,
+        kw.update(n_experts=4, top_k=2, experts_held=0, moe_d_ff=16,
                   first_k_dense=min(1, cfg.first_k_dense),
                   n_shared=min(1, cfg.n_shared))
     if cfg.family == "ssm":

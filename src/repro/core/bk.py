@@ -174,10 +174,12 @@ def record_sq_norm(key: str, act, ds, mode: str, use_kernels: bool,
                    allow_cache: bool = True):
     """Per-sample squared norm for one tapped op.
 
-    Every kind routes through kernels.dispatch: the plan fixes ghost-vs-direct
-    (the paper's layerwise rule; mode 'bk' forces ghost; a ParamGroup's
-    ``method`` override wins over both) and, when ``use_kernels``, whether the
-    fused Pallas kernel or the jnp einsum runs plus its block sizes. Returns
+    mm and emb taps route through kernels.dispatch: the plan fixes
+    ghost-vs-direct (the paper's layerwise rule; mode 'bk' forces ghost; a
+    ParamGroup's ``method`` override wins over both) and, when
+    ``use_kernels``, whether the fused Pallas kernel or the jnp einsum runs
+    plus its block sizes. moe taps take the grouped direct norm of
+    ``ghost.sq_norm_moe``, whatever the mode. Returns
     (sq_norms (B,), cached) where cached optionally carries the instantiated
     per-sample grads for mixopt reuse in phase 3. ``allow_cache=False``
     suppresses that instantiation — mixopt's cache is itself a residency
@@ -249,32 +251,8 @@ def record_sq_norm(key: str, act, ds, mode: str, use_kernels: bool,
             return fn(act, ds), None
         return ghost.sq_norm_emb(act, ds), None
     if kind == "moe":
-        a = act["a"]
-        bdim = a.ndim - 4
-        plan = dispatch.norm_plan("moe", _local(a.shape, bdim, n),
-                                  _local(ds.shape, bdim, n), mode, method)
-        fused = use_kernels and plan.impl == "kernel"
-        rec_specs = {"a": _bspec(a.ndim, bdim, ba),
-                     "mask": _bspec(act["mask"].ndim, bdim, ba)} if n > 1 \
-            else None
-        if plan.method == "ghost":
-            if fused:
-                from repro.kernels import ops as kops
-                if n > 1:
-                    return _shard_call(
-                        mesh, kops.ghost_norm_moe, (act, ds),
-                        (rec_specs, _bspec(ds.ndim, bdim, ba)), P(ba)), None
-                return kops.ghost_norm_moe(act, ds), None
-            return ghost.sq_norm_moe_ghost(act, ds), None
-        if fused:
-            from repro.kernels import ops as kops
-            fn = lambda r, d: kops.direct_norm_moe(r, d, **plan.kwargs())
-            if n > 1:
-                return _shard_call(
-                    mesh, fn, (act, ds),
-                    (rec_specs, _bspec(ds.ndim, bdim, ba)), P(ba)), None
-            return fn(act, ds), None
-        return ghost.sq_norm_moe_direct(act, ds), None
+        # grouped rows of all samples: no kernel, no batch sharding to keep
+        return ghost.sq_norm_moe(act, ds), None
     raise ValueError(f"unknown tap kind in key {key!r}")
 
 
@@ -325,23 +303,6 @@ def record_weighted_grad(key: str, act, ds, C, cached, use_kernels: bool,
                 return fn(act, C, ds).astype(out_dtype)
         return ghost.weighted_grad_emb(act, C, ds, vocab, out_dtype)
     if kind == "moe":
-        if use_kernels:
-            a = act["a"]
-            bdim = a.ndim - 4
-            plan = dispatch.grad_plan("moe", _local(a.shape, bdim, n),
-                                      _local(ds.shape, bdim, n))
-            if plan.impl == "kernel":
-                from repro.kernels import ops as kops
-                fn = lambda r, c, d: kops.clipped_grad_moe(r, c, d,
-                                                           **plan.kwargs())
-                if n > 1:
-                    rec_specs = {"a": _bspec(a.ndim, bdim, ba),
-                                 "mask": _bspec(act["mask"].ndim, bdim, ba)}
-                    return _shard_call(
-                        mesh, fn, (act, C, ds),
-                        (rec_specs, P(ba), _bspec(ds.ndim, bdim, ba)), P(),
-                        psum_axes=ba).astype(out_dtype)
-                return fn(act, C, ds).astype(out_dtype)
         return ghost.weighted_grad_moe(act, C, ds, out_dtype)
     raise ValueError(f"unknown tap kind in key {key!r}")
 

@@ -9,7 +9,7 @@ Implements the paper's modules on book-kept tensors:
 Layouts (see core.tape):
   mm   a (B,T,d)  ds (B,T,p)      stacked: (L,B,T,d) / (L,B,T,p)
   emb  ids (B,T)  ds (B,T,d)      stacked: (L,B,T)   / (L,B,T,d)
-  moe  {'a': (B,E,C,d), 'mask': (B,E,C)}  ds (B,E,C,p)   stacked: +L
+  moe  {'a', 'gs'[, 'rows', 'out', 'w']}    grouped rows of models.moe, +L
 
 All norm accumulation is float32. The fused Pallas kernels in repro.kernels
 compute the same quantities without materializing the (T,T) Grams / (d,p)
@@ -156,85 +156,127 @@ def weighted_grad_emb(ids: jnp.ndarray, C: jnp.ndarray, ds: jnp.ndarray,
 
 
 # ================================================================= MoE (moe)
-def _moe5(rec, ds):
-    a, mask = rec["a"], rec["mask"]
-    if a.ndim == 4:
-        return a[None], mask[None], ds[None]
-    if a.ndim == 5:
-        return a, mask, ds
-    raise ValueError(f"moe record must be 4D or 5D, got {a.shape}")
+# Budget, in f32 elements, of the per-(expert, sample) gradients one step of
+# ``sq_norm_moe``'s loop holds: the input columns are cut into chunks to fit.
+MOE_CHUNK_ELEMS = 1 << 26
 
 
-def sq_norm_moe_ghost(rec: dict, ds: jnp.ndarray) -> jnp.ndarray:
-    """Ghost norm over capacity-gathered expert slots.
-
-    rec['a'] (B,E,C,d) are each sample's tokens routed to each expert
-    (zero-padded to capacity C, validity in rec['mask'] (B,E,C)); ds is the
-    tap cotangent in the same layout. Per-(sample, expert) Gram over the C
-    slots; norms sum over experts (the expert weights are disjoint
-    parameters). Beyond-paper extension — the paper never treats MoE.
-    Large records: lax.map over (layer,sample), one (E,C,C) Gram pair live.
-    """
-    a, mask, ds = _moe5(rec, ds)
-    G, B, E, C, _ = a.shape
-    pe = dict(preferred_element_type=F32)
-    if G * B * E * C * C <= MAP_THRESHOLD:
-        am = a * mask[..., None].astype(a.dtype)
-        dm = ds * mask[..., None].astype(ds.dtype)
-        gram_a = jnp.einsum("lbecd,lbefd->lbecf", am, am, **pe)
-        gram_g = jnp.einsum("lbecp,lbefp->lbecf", dm, dm, **pe)
-        return jnp.einsum("lbecf,lbecf->b", gram_a, gram_g, **pe)
-
-    def one(args):
-        ab, mb, gb = args
-        am = ab * mb[..., None].astype(ab.dtype)
-        dm = gb * mb[..., None].astype(gb.dtype)
-        ga = jnp.einsum("ecd,efd->ecf", am, am, **pe)
-        gg = jnp.einsum("ecp,efp->ecf", dm, dm, **pe)
-        return jnp.sum(ga * gg)
-
-    flat = lambda x: x.reshape((G * B,) + x.shape[2:])
-    n = jax.lax.map(one, (flat(a), flat(mask), flat(ds)))
-    return n.reshape(G, B).sum(0)
+def _moe_layers(rec, ds):
+    """-> (rec, ds, stacked) with a leading layer axis on every leaf."""
+    if rec["gs"].ndim == 3:
+        return rec, ds, True
+    return ({k: v[None] for k, v in rec.items()}, ds[None], False)
 
 
-def sq_norm_moe_direct(rec: dict, ds: jnp.ndarray) -> jnp.ndarray:
-    """Per-(sample,expert) gradient instantiation: g_{be} = a_be^T ds_be."""
-    a, mask, ds = _moe5(rec, ds)
-    G, B, E, _, d = a.shape
-    p = ds.shape[-1]
-    pe = dict(preferred_element_type=F32)
-    if G * B * E * d * p <= MAP_THRESHOLD:
-        dm = ds * mask[..., None].astype(ds.dtype)
-        g = jnp.einsum("lbecd,lbecp->lbedp", a, dm, **pe)
-        return jnp.einsum("lbedp,lbedp->b", g, g)
+def _layer(tree, l):
+    return jax.tree_util.tree_map(
+        lambda v: jax.lax.dynamic_index_in_dim(v, l, keepdims=False), tree)
 
-    def one(args):
-        ab, mb, gb = args
-        dm = gb * mb[..., None].astype(gb.dtype)
-        g = jnp.einsum("ecd,ecp->edp", ab, dm, **pe)
-        return jnp.sum(g * g)
 
-    flat = lambda x: x.reshape((G * B,) + x.shape[2:])
-    n = jax.lax.map(one, (flat(a), flat(mask), flat(ds)))
-    return n.reshape(G, B).sum(0)
+def _a_rows(rec, lo=0, n=None):
+    """Columns [lo, lo + n) of the rows the record's matmul read: ``a``
+    itself, or the rows of ``a`` that ``rows`` indexes (0 where it points
+    past the end)."""
+    a = rec["a"]
+    if n is not None:
+        a = jax.lax.dynamic_slice_in_dim(a, lo, n, 1)
+    if "rows" not in rec:
+        return a
+    return jnp.take(a, rec["rows"], axis=0, mode="fill", fill_value=0)
+
+
+def _ds_rows(rec, ds, dtype):
+    """The cotangent of each row's matmul output: ``ds`` itself, or, where
+    the tap sits on the weighted combine of the rows (``out``: the token of
+    each row, ``w``: its weight), that token's cotangent times the row's
+    weight."""
+    if "out" not in rec:
+        return ds
+    ds = jnp.take(ds.reshape(-1, ds.shape[-1]), rec["out"], axis=0,
+                  mode="fill", fill_value=0)
+    return (ds * rec["w"][:, None]).astype(dtype)
+
+
+def _group_grads(a, ds, sizes):
+    """a (M,d), ds (M,p), ``sizes`` (G,) consecutive row groups ->
+    (G,d,p) f32: sum over each group's rows of a_r^T ds_r. Rows past the
+    last group take no part."""
+    dn = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(([0], [0]), ([], [])),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    return jax.lax.ragged_dot_general(a, ds, sizes, dn,
+                                      preferred_element_type=F32)
+
+
+def _col_chunk(d: int, per_col: int) -> int:
+    """Largest divisor of d, a multiple of 128 where d has one, whose
+    columns times ``per_col`` fit MOE_CHUNK_ELEMS."""
+    fits = [c for c in range(1, d + 1)
+            if d % c == 0 and c * per_col <= MOE_CHUNK_ELEMS]
+    lane = [c for c in fits if c % 128 == 0]
+    return max(lane or fits or [1])
+
+
+def sq_norm_moe(rec: dict, ds: jnp.ndarray) -> jnp.ndarray:
+    """Per-sample squared norm of a grouped expert matmul's weight gradient:
+    n_b = sum_e || sum_{rows r of (e, b)} a_r^T ds_r ||_F^2 (the experts'
+    weights are disjoint parameters).
+
+    The record (models.moe; stacked: a leading layer axis on each leaf)
+    holds ``gs`` (B,H), the rows of each (sample, expert) group, the rows
+    laid out expert-major; ``a``, the rows the matmul read, or the tokens
+    they were gathered from with ``rows`` the token of each row; and, where
+    the tap sits on the weighted combine of the matmul's output rows,
+    ``out`` and ``w`` (``_ds_rows``). The (expert, sample) gradients come
+    from one grouped transposed matmul per layer and column chunk over the
+    rows that carry tokens; one chunk is live at a time.
+    Beyond-paper extension — the paper never treats MoE."""
+    rec, ds, _ = _moe_layers(rec, ds)
+    L, d = rec["gs"].shape[0], rec["a"].shape[-1]
+    B, H = rec["gs"].shape[1:]
+    c = _col_chunk(d, H * B * ds.shape[-1])
+
+    def layer(l):
+        r, dl = _layer(rec, l), _layer(ds, l)
+        dsr = _ds_rows(r, dl, r["a"].dtype)
+        sizes = r["gs"].T.reshape(-1)
+
+        def chunk(j):
+            g = _group_grads(_a_rows(r, j * c, c), dsr, sizes)
+            return jnp.sum(jnp.square(g), axis=(1, 2)).reshape(H, B).sum(0)
+
+        return jax.lax.map(chunk, jnp.arange(d // c)).sum(0)
+
+    return jax.lax.map(layer, jnp.arange(L)).sum(0)
+
+
+def row_weights(gs: jnp.ndarray, C: jnp.ndarray, M: int) -> jnp.ndarray:
+    """(B,H) group sizes, per-sample C (B,) -> (M,) each row's sample's C;
+    0 on rows past the last group."""
+    H = gs.shape[1]
+    vals = jnp.concatenate([jnp.tile(C.astype(F32), H), jnp.zeros((1,), F32)])
+    sizes = jnp.concatenate([gs.T.reshape(-1),
+                             (M - jnp.sum(gs)).reshape(1)])
+    return jnp.repeat(vals, sizes, total_repeat_length=M)
 
 
 def weighted_grad_moe(rec: dict, C: jnp.ndarray, ds: jnp.ndarray,
                       out_dtype=None) -> jnp.ndarray:
-    """G_e = sum_b C_b a_be^T ds_be  -> (E,d,p) or (L,E,d,p)."""
-    a, mask = rec["a"], rec["mask"]
-    out_dtype = out_dtype or a.dtype
-    dsm = ds * mask[..., None].astype(ds.dtype)
-    if a.ndim == 4:
-        g = jnp.einsum("becd,b,becp->edp", a, C.astype(a.dtype), dsm,
-                       preferred_element_type=F32)
-    elif a.ndim == 5:
-        g = jnp.einsum("lbecd,b,lbecp->ledp", a, C.astype(a.dtype), dsm,
-                       preferred_element_type=F32)
-    else:
-        raise ValueError(f"moe record must be 4D or 5D, got {a.shape}")
-    return g.astype(out_dtype)
+    """G_e = sum_b C_b sum_{rows r of (e, b)} a_r^T ds_r -> (H,d,p) or
+    (L,H,d,p): one grouped transposed matmul per layer over the held
+    experts, each row weighted by its sample's clip factor."""
+    rec, ds, stacked = _moe_layers(rec, ds)
+    out_dtype = out_dtype or rec["a"].dtype
+
+    def layer(l):
+        r, dl = _layer(rec, l), _layer(ds, l)
+        a = _a_rows(r)
+        w = row_weights(r["gs"], C, a.shape[0]).astype(a.dtype)
+        return _group_grads(a * w[:, None], _ds_rows(r, dl, a.dtype),
+                            jnp.sum(r["gs"], axis=0)).astype(out_dtype)
+
+    g = jax.lax.map(layer, jnp.arange(rec["gs"].shape[0]))
+    return g if stacked else g[0]
 
 
 # ====================================================== hybrid decision rule

@@ -12,7 +12,7 @@ parameter machinery.
 Key naming: ``<path>#<kind>[.s]`` where kind is one of
   mm   — matmul: record = activation a, layouts (B,T,d) / stacked (L,B,T,d)
   emb  — embedding lookup: record = int ids (B,T) / (L,B,T)
-  moe  — gathered expert matmul: record = {'a': (B,E,C,d), 'mask': (B,E,C)}
+  moe  — grouped expert matmul: record = {'a': rows (M,d), 'gs': (B,H), ...}
 and the ``.s`` suffix marks records stacked over a leading scan (layer) axis.
 
 The parameter owned by a tapped op lives at ``<path>/w`` in the params tree.
@@ -179,7 +179,7 @@ def fix_scan_params(tree: dict, tapped: bool) -> dict:
 #              second chunked backward sweep over the phase-1 linearization
 #   auto       per-tap choice by the dispatch residency planner
 #              (kernels.dispatch.tape_plan)
-# Integer / bool leaves (embedding ids, MoE masks) are already compact and
+# Integer / bool leaves (embedding ids, MoE group sizes) are already compact and
 # always pass through untouched.
 TAPE_POLICIES = ("native", "bf16", "int8", "recompute", "auto")
 
@@ -228,7 +228,7 @@ def store_record(x, policy: str, rng=None):
     rounding draw."""
     if policy in ("native", "recompute"):
         return x
-    if isinstance(x, dict):          # moe record {'a': float, 'mask': ...}
+    if isinstance(x, dict):          # moe record {'a': float, 'gs': int}
         out = dict(x)
         out["a"] = store_record(x["a"], policy, rng)
         return out

@@ -173,16 +173,8 @@ def norm_plan(kind: str, act_shape, ds_shape, mode: str,
             return _blocked(L * B * T * T, "ghost", ("block_t",),
                             block_t_ghost(T, d, d, lane=True))
         if kind == "moe":
-            a = act_shape if len(act_shape) == 5 else (1,) + tuple(act_shape)
-            L, B, E, C, d = a
-            p = ds_shape[-1]
-            from repro.core.ghost import prefer_ghost
-            m = method or ("ghost" if mode == "bk" or prefer_ghost(C, d, p)
-                           else "direct")
-            if m == "ghost":
-                return Plan(_impl(L * B * E * 2 * C * C), m, ())
-            return _blocked(L * B * E * d * p, m, ("block_d", "block_p"),
-                            block_dp(C, d, p))
+            # grouped rows (models.moe): the jnp grouped direct norm
+            return Plan("jnp", "direct", ())
         raise ValueError(f"unknown tap kind {kind!r}")
 
     return _cached(key, mk)
@@ -248,11 +240,7 @@ def grad_plan(kind: str, act_shape, ds_shape, vocab: int = 0) -> Plan:
             return _blocked(L * B * T * d, "scatter", ("block_v",),
                             block_v(T, d, vocab))
         if kind == "moe":
-            a = act_shape if len(act_shape) == 5 else (1,) + tuple(act_shape)
-            L, B, E, C, d = a
-            p = ds_shape[-1]
-            return _blocked(L * B * E * C * p, "direct", ("block_d", "block_p"),
-                            block_dp(C, d, p))
+            return Plan("jnp", "direct", ())
         raise ValueError(f"unknown tap kind {kind!r}")
 
     return _cached(key, mk)

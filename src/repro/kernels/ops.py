@@ -1,5 +1,5 @@
 """Jit'd wrappers around the Pallas kernels, with the layout handling the DP
-engine expects (stacked layer dims, padding, moe record dicts) and automatic
+engine expects (stacked layer dims, padding) and automatic
 interpret-mode on CPU (kernels are validated on CPU via interpret=True; TPU
 v5e is the compile target). Policy — which kernel, which blocks — lives in
 repro.kernels.dispatch; these wrappers are pure mechanism."""
@@ -15,9 +15,6 @@ from repro.kernels.emb_norm import emb_ghost_norm as _emb_norm
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.ghost_norm import ghost_norm as _ghost_norm
 from repro.kernels.grad_norm_direct import grad_norm_direct as _direct
-from repro.kernels.moe_ghost import (moe_clipped_grad as _moe_grad,
-                                     moe_direct_norm as _moe_direct,
-                                     moe_ghost_norm as _moe_ghost)
 from repro.kernels.wkv6 import wkv6 as _wkv6
 
 
@@ -60,23 +57,6 @@ def clipped_grad_emb(ids, C, ds, vocab: int, block_v: int = 512):
     """-> (vocab,d) f32, or (L,vocab,d) for stacked records."""
     return _emb_grad(ids, C, ds, vocab=vocab, block_v=block_v,
                      interpret=_interpret())
-
-
-# ----------------------------------------------------------------- moe taps
-def ghost_norm_moe(rec, ds):
-    """rec {'a': (B,E,C,d)[+L], 'mask': (B,E,C)[+L]}, ds (B,E,C,p)[+L] -> (B,)."""
-    return _moe_ghost(rec["a"], rec["mask"], ds, interpret=_interpret())
-
-
-def direct_norm_moe(rec, ds, block_d: int = 256, block_p: int = 256):
-    return _moe_direct(rec["a"], rec["mask"], ds, block_d=block_d,
-                       block_p=block_p, interpret=_interpret())
-
-
-def clipped_grad_moe(rec, C, ds, block_d: int = 256, block_p: int = 256):
-    """-> (E,d,p) f32, or (L,E,d,p) for stacked records. One launch."""
-    return _moe_grad(rec["a"], rec["mask"], C, ds, block_d=block_d,
-                     block_p=block_p, interpret=_interpret())
 
 
 # ------------------------------------------------------------------- others

@@ -148,10 +148,8 @@ def autotune_warmup(apply_fn, params, batch, dp, log=print) -> int:
         ("norm", "mm", "ghost"): kops.ghost_norm_mm,
         ("norm", "mm", "direct"): kops.direct_norm_mm,
         ("norm", "emb", "ghost"): kops.ghost_norm_emb,
-        ("norm", "moe", "direct"): kops.direct_norm_moe,
         ("grad", "mm", "direct"): kops.clipped_grad_mm,
         ("grad", "emb", "scatter"): kops.clipped_grad_emb,
-        ("grad", "moe", "direct"): kops.clipped_grad_moe,
     }
 
     rng = jax.random.PRNGKey(0)
@@ -159,10 +157,10 @@ def autotune_warmup(apply_fn, params, batch, dp, log=print) -> int:
     for key in sorted(acts):
         path, kind, _ = parse_key(key)
         wpath = path + "/w"
-        if wpath in res.frozen:
+        if wpath in res.frozen or kind == "moe":  # moe: no kernel to tune
             continue
         method = res.method_for(wpath)
-        a_struct = acts[key]["a"] if kind == "moe" else acts[key]
+        a_struct = acts[key]
         ds_struct = taps[key]
         vocab = flat_params[wpath].shape[-2] if kind == "emb" else 0
         cell = (kind, tuple(a_struct.shape), tuple(ds_struct.shape), vocab,
@@ -171,11 +169,8 @@ def autotune_warmup(apply_fn, params, batch, dp, log=print) -> int:
             continue
         seen.add(cell)
 
-        B = ds_struct.shape[-4] if kind == "moe" else ds_struct.shape[-3]
-        act = (dict(a=_synth(acts[key]["a"], rng),
-                    mask=jnp.ones(acts[key]["mask"].shape,
-                                  acts[key]["mask"].dtype))
-               if kind == "moe" else _synth(acts[key], rng, vocab))
+        B = ds_struct.shape[-3]
+        act = _synth(acts[key], rng, vocab)
         ds = _synth(ds_struct, rng)
         C = jnp.ones((B,), jnp.float32)
 

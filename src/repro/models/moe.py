@@ -1,13 +1,31 @@
-"""Mixture-of-Experts with per-(sample, expert) capacity dispatch.
+"""Mixture-of-Experts layer that holds a share of the routed experts:
+drop-free, with routing per sample.
 
-DP correctness note: classic GShard-style dispatch shares expert capacity
-across the whole token batch, which makes one sample's gradient depend on
-*other* samples' routing (capacity overflow drops) — that breaks the
-per-sample sensitivity analysis DP-SGD relies on. Here capacity is allocated
-per (sample, expert): routing, drops and therefore per-sample gradients are
-functions of the sample alone. This also makes the per-(b,e) token groups the
-natural ghost-norm unit (Gram over each sample's routed tokens) — the
-beyond-paper MoE extension of the BK algorithm (DESIGN.md §6).
+The router scores all ``n_experts`` routed experts in f32 and keeps the
+``top_k`` best of each token. The layer holds the contiguous range
+``held(cfg)`` of them (expert parallelism: the other experts live on other
+chips) and computes only its own experts' part of the result, for the tokens
+routed to them; what the absent experts would add is left out, and no
+exchange stands in for them.
+
+Every (token, held expert) assignment gets a row of a static buffer sized
+for the worst case, B * T * min(top_k, held) rows, so nothing is ever
+dropped. The rows are laid out expert by expert and, inside an expert,
+sample by sample; ``gs`` (B, held) counts the rows of each (sample, expert)
+group. The grouped matmuls (``jax.lax.ragged_dot``) read only the rows that
+carry tokens; rows past the last group give zeros.
+
+DP: routing is a function of each token alone and no capacity is shared, so
+a sample's gradient depends on that sample alone. The expert matmuls are
+``moe`` taps: a sample's gradient of expert e is the sum over the rows of
+its (e, sample) group (``core.ghost.sq_norm_moe``). Their records keep what
+the rows are made from, not the buffer: the up matmul's the tokens and the
+token of each row, the down matmul's tap sits on the weighted combine of
+its output rows. The router is an ordinary ``mm`` tap.
+
+Profiler scopes: ``moe_dispatch`` (router, top-k, the row layout, the gather
+into rows and the weighted combine) and ``moe_experts`` (the grouped
+matmuls and the gate between them).
 """
 from __future__ import annotations
 
@@ -15,27 +33,36 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 
+F32 = jnp.float32
 
-def capacity(cfg: ModelConfig, T: int) -> int:
-    cap = int(math.ceil(cfg.capacity_factor * cfg.top_k * T / cfg.n_experts))
-    return max(1, min(cap, T))
+
+def held(cfg: ModelConfig) -> tuple:
+    """-> (first, count): the routed experts this layer holds."""
+    n = cfg.experts_held or cfg.n_experts
+    if not 0 <= cfg.first_expert <= cfg.first_expert + n <= cfg.n_experts:
+        raise ValueError(f"{cfg.name}: experts [{cfg.first_expert}, "
+                         f"{cfg.first_expert + n}) are not among the "
+                         f"{cfg.n_experts} routed experts")
+    return cfg.first_expert, n
 
 
 def moe_init(rng, cfg: ModelConfig):
-    d, E, ff = cfg.d_model, cfg.n_experts, cfg.moe_d_ff or cfg.d_ff
+    d, ff = cfg.d_model, cfg.moe_d_ff or cfg.d_ff
+    _, H = held(cfg)
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(rng, 4)
     mult = 2 if cfg.act == "swiglu" else 1
     p = {
-        "router": L.linear_init(ks[0], d, E, dt),
+        "router": L.linear_init(ks[0], d, cfg.n_experts, dt),
         "experts": {
-            "up": {"w": L.normal_init(ks[1], (E, d, mult * ff), dt,
+            "up": {"w": L.normal_init(ks[1], (H, d, mult * ff), dt,
                                       1.0 / math.sqrt(d))},
-            "down": {"w": L.normal_init(ks[2], (E, ff, d), dt,
+            "down": {"w": L.normal_init(ks[2], (H, ff, d), dt,
                                         1.0 / math.sqrt(ff))},
         },
     }
@@ -45,65 +72,134 @@ def moe_init(rng, cfg: ModelConfig):
     return p
 
 
-def moe_linear(tape, name, p, xg, valid, act_in):
-    """Tapped expert matmul: xg (B,E,C,din) @ w (E,din,dout).
+# ---------------------------------------------------------- grouped matmul
+# ``jax.lax.ragged_dot`` batches under vmap only with every operand mapped
+# on axis 0, and custom_vmap rules cannot be differentiated in reverse
+# mode; so the grouped matmul is a custom_vjp whose forward and backward
+# are each a custom_vmap (under vmap: one mapped element at a time).
+# Without vmap both are the plain ragged dots.
+_DW = jax.lax.RaggedDotDimensionNumbers(             # (M,d) x (M,p) -> (H,d,p)
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
-    The tap record keeps (activation, slot-validity mask) — the unit of the
-    per-(sample, expert) ghost norm.
-    """
-    s = jnp.einsum("becd,edf->becf", xg, p["w"])
-    return tape.record(name, "moe", s, {"a": act_in, "mask": valid})
+
+def _one_by_one(fn):
+    """``fn`` as a custom_vmap whose rule maps over the batch one element
+    at a time (unbatched operands broadcast)."""
+    wrapped = custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        return jax.lax.map(lambda xs: fn(*xs), tuple(args)), True
+
+    return wrapped
+
+
+_gmm = _one_by_one(lambda x, w, n: jax.lax.ragged_dot(x, w, n))
+# the input cotangent multiplies by each expert's transposed weight; a TPU
+# runs a ragged dot that contracts the weight's last dim as a dense masked
+# product over every group, so the weight is transposed first
+_gmm_dx = _one_by_one(lambda ct, w, n: jax.lax.ragged_dot(
+    ct, jnp.swapaxes(w, 1, 2), n))
+_gmm_dw = _one_by_one(lambda x, ct, n: jax.lax.ragged_dot_general(
+    x, ct, n, _DW, preferred_element_type=F32))
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, sizes):
+    """x (M, din) @ w (H, din, dout): the first sizes[0] rows by w[0], the
+    next sizes[1] by w[1], ...; rows past them give zeros."""
+    return _gmm(x, w, sizes)
+
+
+def _gmm_fwd(x, w, sizes):
+    return _gmm(x, w, sizes), (x, w, sizes)
+
+
+def _gmm_bwd(res, ct):
+    x, w, sizes = res
+    return (_gmm_dx(ct, w, sizes), _gmm_dw(x, ct, sizes).astype(w.dtype),
+            None)
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def route(p, tape, x, cfg: ModelConfig):
+    """x (B,T,d) -> (row (B,T,k) int32, weight (B,T,k) f32, gs (B,H) int32):
+    the buffer row of each of a token's top-k experts (M, one past the
+    buffer, where the expert is not held), the router's score for it (0
+    where not held), and the rows of each (sample, held expert) group."""
+    B, T, _ = x.shape
+    first, H = held(cfg)
+    M = B * T * min(cfg.top_k, H)
+    logits = jnp.einsum("btd,de->bte", x, p["router"]["w"],
+                        preferred_element_type=F32)
+    logits = tape.record("router", "mm", logits, x)
+    probs = jax.nn.softmax(logits, axis=-1)
+    topv, topi = jax.lax.top_k(probs, cfg.top_k)                  # (B,T,k)
+    if cfg.renorm_topk:
+        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    e = topi - first
+    mine = (e >= 0) & (e < H)
+    e = jnp.where(mine, e, 0)
+    # a token picks each expert at most once, so (B,T,H) holds 0 or 1
+    sel = jnp.sum(jax.nn.one_hot(e, H, dtype=jnp.int32)
+                  * mine[..., None].astype(jnp.int32), axis=2)
+    rank = jnp.cumsum(sel, axis=1) - sel             # rows of (b, e) before t
+    gs = jnp.sum(sel, axis=1)                                     # (B, H)
+    sizes = gs.T.reshape(-1)                        # expert-major, then sample
+    start = (jnp.cumsum(sizes) - sizes).reshape(H, B).T              # (B, H)
+    b_ix = jnp.arange(B)[:, None, None]
+    t_ix = jnp.arange(T)[None, :, None]
+    row = start[b_ix, e] + rank[b_ix, t_ix, e]
+    row = jnp.where(mine, row, M)
+    return row, jnp.where(mine, topv, 0.0), gs
 
 
 def moe_apply(p, tape, x, cfg: ModelConfig):
-    """x (B,T,d) -> (B,T,d)."""
+    """x (B,T,d) -> (B,T,d): the held experts' part plus the shared
+    experts."""
     B, T, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
-    cap = capacity(cfg, T)
+    _, H = held(cfg)
+    M = B * T * min(cfg.top_k, H)
+    xs = x.reshape(B * T, d)
+    with jax.named_scope("moe_dispatch"):
+        row, weight, gs = route(p, tape, x, cfg)
+        # token and router weight of each buffer row; B*T (no token) and 0
+        # on the rows left empty
+        slot_tok = jnp.repeat(jnp.arange(B * T, dtype=jnp.int32), cfg.top_k)
+        tok = jnp.full((M,), B * T, jnp.int32).at[row.reshape(-1)].set(
+            slot_tok, mode="drop")
+        row_w = jnp.zeros((M,), F32).at[row.reshape(-1)].set(
+            weight.reshape(-1), mode="drop")
+        rows = jnp.take(xs, tok, axis=0, mode="fill", fill_value=0)
 
-    logits = L.linear(tape, "router", p["router"], x).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                       # (B,T,E)
-    topv, topi = jax.lax.top_k(probs, k)                          # (B,T,k)
-    if cfg.renorm_topk:
-        topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    sel = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.float32), axis=2)  # (B,T,E)
-    weight = jnp.einsum("btk,btke->bte", topv,
-                        jax.nn.one_hot(topi, E, dtype=jnp.float32))
-
-    # --- per-(b,e) slot assignment --------------------------------------
-    pos = jnp.cumsum(sel, axis=1) - 1.0                           # (B,T,E)
-    pos = pos.astype(jnp.int32)
-    keep = (sel > 0) & (pos < cap)
-    b_ix = jnp.arange(B)[:, None, None]
-    e_ix = jnp.arange(E)[None, None, :]
-    t_ix = jnp.broadcast_to(jnp.arange(T)[None, :, None], (B, T, E))
-    slot_pos = jnp.where(keep, pos, cap)                          # cap -> dropped
-    slot_t = jnp.zeros((B, E, cap), jnp.int32).at[
-        b_ix, e_ix, slot_pos].set(t_ix, mode="drop")
-    valid = jnp.zeros((B, E, cap), jnp.float32).at[
-        b_ix, e_ix, slot_pos].set(1.0, mode="drop")
-
-    xg = x[jnp.arange(B)[:, None, None], slot_t]                  # (B,E,C,d)
-    xg = xg * valid[..., None].astype(xg.dtype)
-
-    # --- expert FFN (tapped) ---------------------------------------------
-    with tape.scope("experts"):
-        ep = p["experts"]
-        u = moe_linear(tape, "up", ep["up"], xg, valid, xg)
+    sizes = jnp.sum(gs, axis=0)
+    ep = p["experts"]
+    with jax.named_scope("moe_experts"), tape.scope("experts"):
+        u = grouped_matmul(rows, ep["up"]["w"], sizes)
+        # the record keeps the tokens and which token each row is, not
+        # the gathered rows
+        u = tape.record("up", "moe", u, {"a": xs, "rows": tok, "gs": gs})
         if cfg.act == "swiglu":
             g, u = jnp.split(u, 2, axis=-1)
             h = jax.nn.silu(g) * u
         else:
             h = jax.nn.gelu(u)
-        h = h * valid[..., None].astype(h.dtype)
-        out = moe_linear(tape, "down", ep["down"], h, valid, h)
-        out = out * valid[..., None].astype(out.dtype)
+        out = grouped_matmul(h, ep["down"]["w"], sizes)
 
-    # --- combine ----------------------------------------------------------
-    g_slot = jnp.clip(pos, 0, cap - 1)                            # (B,T,E)
-    per_e = out[b_ix, e_ix, g_slot]                               # (B,T,E,d)
-    w_eff = (weight * keep.astype(weight.dtype)).astype(per_e.dtype)
-    y = jnp.einsum("bted,bte->btd", per_e, w_eff)
+    with jax.named_scope("moe_dispatch"):
+        picked = jnp.take(out, row, axis=0, mode="fill", fill_value=0)
+        y = jnp.sum(picked * weight[..., None], axis=2)           # (B,T,d)
+    with jax.named_scope("moe_experts"), tape.scope("experts"):
+        # the down matmul's tap sits on the weighted combine: a row's
+        # output cotangent is its weight times its token's, so the (B,T,d)
+        # cotangent stands for the (M,d) one
+        y = tape.record("down", "moe", y,
+                        {"a": h, "out": tok, "w": row_w, "gs": gs})
 
     if cfg.n_shared:
         from repro.models.transformer import mlp_apply

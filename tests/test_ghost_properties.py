@@ -67,20 +67,30 @@ def test_weighted_grad_mm(B, T, d, p, seed):
 
 
 @settings(max_examples=15, deadline=None)
-@given(B=st.integers(1, 3), E=st.integers(1, 4), C=st.integers(1, 5),
-       d=st.integers(1, 5), p=st.integers(1, 5), seed=st.integers(0, 2**16))
-def test_moe_ghost_vs_direct(B, E, C, d, p, seed):
+@given(B=st.integers(1, 3), H=st.integers(1, 4), d=st.integers(1, 5),
+       p=st.integers(1, 5), pad=st.integers(0, 4), seed=st.integers(0, 2**16))
+def test_moe_grouped_norm_and_sum(B, H, d, p, pad, seed):
+    """Grouped rows (models.moe): norms and clipped sums against the
+    per-(expert, sample) gradients made one group at a time."""
     rng = np.random.RandomState(seed)
-    a = arrays((B, E, C, d), seed)
-    mask = jnp.asarray((rng.rand(B, E, C) > 0.3).astype(np.float32))
-    ds = arrays((B, E, C, p), seed + 1)
-    rec = {"a": a, "mask": mask}
-    am = np.asarray(a) * np.asarray(mask)[..., None]
-    dm = np.asarray(ds) * np.asarray(mask)[..., None]
-    g = np.einsum("becd,becp->bedp", am, dm)
-    want = np.sum(g * g, axis=(1, 2, 3))
-    np.testing.assert_allclose(ghost.sq_norm_moe_ghost(rec, ds), want, rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(ghost.sq_norm_moe_direct(rec, ds), want, rtol=1e-4, atol=1e-5)
+    gs = rng.randint(0, 4, size=(B, H)).astype(np.int32)
+    M = int(gs.sum()) + pad
+    a = arrays((M, d), seed)
+    ds = arrays((M, p), seed + 1)
+    C = jnp.abs(arrays((B,), seed + 2)) + 0.1
+    an, dn = np.asarray(a), np.asarray(ds)
+    want_n, want_g, r = np.zeros(B), np.zeros((H, d, p)), 0
+    for e in range(H):
+        for b in range(B):
+            g = an[r:r + gs[b, e]].T @ dn[r:r + gs[b, e]]
+            want_n[b] += np.sum(g * g)
+            want_g[e] += np.asarray(C)[b] * g
+            r += gs[b, e]
+    rec = {"a": a, "gs": jnp.asarray(gs)}
+    np.testing.assert_allclose(ghost.sq_norm_moe(rec, ds), want_n,
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(ghost.weighted_grad_moe(rec, C, ds), want_g,
+                               rtol=1e-4, atol=1e-5)
 
 
 def test_hybrid_rule_matches_paper_examples():

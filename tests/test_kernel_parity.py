@@ -136,60 +136,11 @@ def test_emb_kernels_unstacked():
                                **TOL)
 
 
-# --------------------------------------------------------------------- moe
-MOE_SHAPES = [(1, 2, 3, 5, 12, 20), (2, 2, 4, 7, 9, 13), (2, 3, 2, 16, 24, 8)]
-
-
-def _moe(L, B, E, C, d, p, dtype):
-    a = _mk((L, B, E, C, d), dtype)
-    mask = (jax.random.uniform(jax.random.PRNGKey(4),
-                               (L, B, E, C)) > 0.3).astype(F32)
-    ds = _mk((L, B, E, C, p), dtype, 1)
-    return {"a": a, "mask": mask}, ds
-
-
-@pytest.mark.parametrize("L,B,E,C,d,p", MOE_SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_moe_ghost_norm_parity(L, B, E, C, d, p, dtype):
-    rec, ds = _moe(L, B, E, C, d, p, dtype)
-    want = ghost.sq_norm_moe_ghost(rec, ds)
-    got = ops.ghost_norm_moe(rec, ds)
-    np.testing.assert_allclose(got, want, **_tol(dtype))
-
-
-@pytest.mark.parametrize("L,B,E,C,d,p", MOE_SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_moe_direct_norm_parity(L, B, E, C, d, p, dtype):
-    rec, ds = _moe(L, B, E, C, d, p, dtype)
-    want = ghost.sq_norm_moe_direct(rec, ds)
-    got = ops.direct_norm_moe(rec, ds, block_d=8, block_p=8)
-    np.testing.assert_allclose(got, want, **_tol(dtype))
-
-
-@pytest.mark.parametrize("L,B,E,C,d,p", MOE_SHAPES)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_moe_clipped_grad_parity(L, B, E, C, d, p, dtype):
-    rec, ds = _moe(L, B, E, C, d, p, dtype)
-    Cw = jnp.abs(_mk((B,), F32, 2)) + 0.1
-    want = ghost.weighted_grad_moe(rec, Cw, ds, F32)
-    got = ops.clipped_grad_moe(rec, Cw, ds, block_d=8, block_p=8)
-    assert got.shape == (L, E, d, p)
-    np.testing.assert_allclose(got, want, **_tol(dtype))
-
-
-def test_moe_ghost_equals_direct_kernels():
-    rec, ds = _moe(2, 2, 3, 6, 10, 14, F32)
-    np.testing.assert_allclose(ops.ghost_norm_moe(rec, ds),
-                               ops.direct_norm_moe(rec, ds, block_d=8,
-                                                   block_p=8), rtol=1e-4)
-
-
 # ---------------------------------------------------------------- dispatch
 def test_dispatch_prefers_kernels_for_real_shapes():
     for kind, a_shape, ds_shape in [
             ("mm", (2, 8, 128, 64), (2, 8, 128, 64)),
-            ("emb", (2, 8, 128), (2, 8, 128, 64)),
-            ("moe", (2, 8, 4, 32, 64), (2, 8, 4, 32, 48))]:
+            ("emb", (2, 8, 128), (2, 8, 128, 64))]:
         plan = dispatch.norm_plan(kind, a_shape, ds_shape, "bk")
         assert plan.impl == "kernel", (kind, plan)
         assert plan.method == "ghost"

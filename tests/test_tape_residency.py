@@ -236,10 +236,10 @@ def test_store_load_roundtrip():
 def test_store_load_integer_and_moe_records():
     ids = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
     assert store_record(ids, "int8") is ids       # ids stay exact
-    moe = {"a": jax.random.normal(jax.random.PRNGKey(0), (2, 2, 3, 4)),
-           "mask": jnp.ones((2, 2, 3), jnp.bool_)}
+    moe = {"a": jax.random.normal(jax.random.PRNGKey(0), (2, 12, 4)),
+           "gs": jnp.ones((2, 2, 3), jnp.int32)}
     s = store_record(moe, "bf16")
-    assert s["a"].dtype == jnp.bfloat16 and s["mask"] is moe["mask"]
+    assert s["a"].dtype == jnp.bfloat16 and s["gs"] is moe["gs"]
     out = load_record(s, moe["a"].dtype)
     assert out["a"].dtype == moe["a"].dtype
     np.testing.assert_allclose(np.asarray(out["a"]), np.asarray(moe["a"]),

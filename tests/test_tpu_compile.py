@@ -1,4 +1,5 @@
-"""Compile the BK step's Pallas kernels for a described TPU v5e.
+"""Compile the BK step's Pallas kernels, and the expert layer's ragged
+dots, for a described TPU v5e.
 
 Interpret mode (the CPU path of every other test) accepts blocks that
 Mosaic refuses, so each main-path kernel is compiled here, at qwen2-1.5b
@@ -204,3 +205,45 @@ def test_noise_draw_keeps_each_leafs_shape(one_chip, monkeypatch):
             if (m := re.match(r"\s*(?:ROOT )?%\S+ = \w+\[(\d+)\]", line))
             and int(m.group(1)) in sizes]
     assert not flat, flat
+
+
+# deepseek-moe-16b's expert layer on one chip's share: 8 held experts of
+# width 1408, 8 rows x 1024 tokens, top 6 -> a buffer of 49152 rows
+EM, ED, EF, EH, EB = 8 * 1024 * 6, 2048, 1408, 8, 8
+
+
+def _ragged_kernels(text):
+    return re.findall(r"%ragged-dot-none(?:\.\d+)? = ", text)
+
+
+def test_grouped_matmul_and_its_input_cotangent(one_chip):
+    """The expert layer's matmul and its backward to the rows are each
+    the TPU's ragged-dot kernel: no dense product over every expert (a
+    ragged dot contracting the weight's last dim lowers to one, the expert
+    count times the buffer)."""
+    from repro.models.moe import grouped_matmul
+
+    def fwd_bwd(x, w, ct, sizes):
+        y, vjp = jax.vjp(lambda x: grouped_matmul(x, w, sizes), x)
+        return y, vjp(ct)[0]
+
+    text = _compile(one_chip, fwd_bwd, ((EM, ED), BF16),
+                    ((EH, ED, 2 * EF), BF16), ((EM, 2 * EF), BF16),
+                    ((EH,), I32)).as_text()
+    assert len(_ragged_kernels(text)) == 2
+    assert not re.search(rf"= \w+\[{EH},{EM},", text)
+
+
+def test_grouped_norms_and_clipped_sums(one_chip):
+    """BK's expert norms and clipped sums: one ragged-dot kernel each, over
+    the (expert, sample) groups and over the experts."""
+    def norms_sums(a, tok, ds, gs, C):
+        rec = {"a": a, "rows": tok, "gs": gs}
+        return (ghost.sq_norm_moe(rec, ds),
+                ghost.weighted_grad_moe(rec, C, ds, BF16))
+
+    text = _compile(one_chip, norms_sums, ((5, EB * 1024, ED), BF16),
+                    ((5, EM), I32), ((5, EM, 2 * EF), BF16),
+                    ((5, EB, EH), I32), ((EB,), F32)).as_text()
+    kernels = re.findall(r"= f32\[(\d+),\d+,\d+\][^ ]* custom-call\(", text)
+    assert sorted(set(int(k) for k in kernels)) == [EH, EH * EB]
